@@ -10,10 +10,11 @@ ROADMAP.  Both paths of each pair run the *same* iteration count (a
 deliberately infeasible-in-N-iterations bound configuration), so wall-clock
 ratios are per-iteration ratios.
 
-The sharded-backend case needs >1 device, so it runs in a subprocess with
-``--xla_force_host_platform_device_count`` set (fake CPU devices share the
-same physical cores, so the row measures shard_map overhead/parity on CPU;
-real distribution wins land on a multi-chip mesh).
+The sharded-backend case needs >1 device.  Under ``JAX_PLATFORMS=cpu`` it
+runs in a child process with ``--xla_force_host_platform_device_count`` set
+(fake CPU devices share the same physical cores, so the row measures
+shard_map overhead/parity on CPU); on real devices it runs in this process,
+over the devices JAX reports.  A failed child fails the script.
 
 Usage:  PYTHONPATH=src python benchmarks/bench_pocs.py [--quick]
 """
@@ -27,12 +28,19 @@ import subprocess
 import sys
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+# Decided before JAX is imported: fake host devices exist only if XLA_FLAGS
+# asks for them at import, so the CPU backend needs a child process for the
+# sharded cases, while real devices run them here (a child could not reach a
+# chip the parent already holds).
+FAKE_DEVICE_CHILD = os.environ.get("JAX_PLATFORMS", "") == "cpu"
 
-from repro.core.blockwise import blockwise_correct, correct_batch
-from repro.core.pocs import alternating_projection
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.core.blockwise import blockwise_correct, correct_batch  # noqa: E402
+from repro.core.pocs import alternating_projection  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def _bench(fn, repeat: int = 5):
@@ -553,9 +561,42 @@ def bench_backends_child(n_devices: int, n_tensors: int, size: int, block: int, 
     ]
 
 
+def backend_rows(n_devices: int, quick: bool):
+    """Batched vs sharded backends and fused vs pencil-sharded whole fields
+    over ``n_devices`` devices (fake ones in the child, real ones here)."""
+    rows = bench_backends_child(
+        n_devices=n_devices,
+        n_tensors=16 if quick else 64,
+        size=4096,
+        block=4096,
+        max_iters=8,
+        repeat=3 if quick else 16,
+    )
+    rows += bench_dist_field_child(
+        n_devices=n_devices,
+        shape=(64, 32, 16) if quick else (128, 128, 64),
+        max_iters=8 if quick else 20,
+        repeat=3 if quick else 16,
+    )
+    # uneven padded decomposition: axis 0 non-divisible by the mesh and
+    # non-power-of-two (the generalized-slab code path end to end)
+    rows += bench_dist_field_child(
+        n_devices=n_devices,
+        shape=(30, 32, 16) if quick else (100, 80, 56),
+        max_iters=8 if quick else 20,
+        repeat=3 if quick else 16,
+        suffix="-uneven",
+    )
+    return rows
+
+
 def bench_backends(n_devices: int, quick: bool):
-    """Spawn the sharded-vs-batched comparison on a fake multi-device mesh
-    (XLA_FLAGS must be set before jax import, hence the subprocess)."""
+    """The sharded cases: in a child with ``n_devices`` fake host devices on
+    the CPU backend (XLA_FLAGS must be set before jax import), in this
+    process over the real devices otherwise.  A failed child exits the
+    script nonzero rather than dropping rows."""
+    if not FAKE_DEVICE_CHILD:
+        return backend_rows(len(jax.devices()), quick)
     env = dict(os.environ)
     # append so caller-supplied compiler flags apply to this row too
     env["XLA_FLAGS"] = (
@@ -564,18 +605,14 @@ def bench_backends(n_devices: int, quick: bool):
     cmd = [sys.executable, os.path.abspath(__file__), _BACKEND_CHILD, str(n_devices)]
     if quick:
         cmd.append("--quick")
-    try:
-        proc = subprocess.run(capture_output=True, text=True, env=env, args=cmd, timeout=600)
-    except subprocess.TimeoutExpired:
-        print("backend bench subprocess timed out; skipping the backend rows")
-        return []
-    if proc.returncode != 0:
-        print(f"backend bench subprocess failed:\n{proc.stderr[-2000:]}")
-        return []
+    proc = subprocess.run(capture_output=True, text=True, env=env, args=cmd, timeout=600)
     lines = [l for l in proc.stdout.splitlines() if l.startswith("ROWS:")]
-    if not lines:
-        print("backend bench subprocess produced no ROWS line; skipping")
-        return []
+    if proc.returncode != 0 or not lines:
+        sys.stderr.write(proc.stderr)
+        raise SystemExit(
+            f"backend bench child exited {proc.returncode} "
+            f"{'with' if lines else 'without'} a ROWS line"
+        )
     return json.loads(lines[0][len("ROWS:"):])
 
 
@@ -586,32 +623,10 @@ def main():
     ap.add_argument(_BACKEND_CHILD, type=int, default=0, dest="backend_child", help=argparse.SUPPRESS)
     ap.add_argument("--devices", type=int, default=8, help="fake device count for the sharded-backend case")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.backend_child:
-        rows = bench_backends_child(
-            n_devices=args.backend_child,
-            n_tensors=16 if args.quick else 64,
-            size=4096,
-            block=4096,
-            max_iters=8,
-            repeat=3 if args.quick else 16,
-        )
-        rows += bench_dist_field_child(
-            n_devices=args.backend_child,
-            shape=(64, 32, 16) if args.quick else (128, 128, 64),
-            max_iters=8 if args.quick else 20,
-            repeat=3 if args.quick else 16,
-        )
-        # uneven padded decomposition: axis 0 non-divisible by the mesh and
-        # non-power-of-two (the generalized-slab code path end to end)
-        rows += bench_dist_field_child(
-            n_devices=args.backend_child,
-            shape=(30, 32, 16) if args.quick else (100, 80, 56),
-            max_iters=8 if args.quick else 20,
-            repeat=3 if args.quick else 16,
-            suffix="-uneven",
-        )
-        print("ROWS:" + json.dumps(rows))
+        print("ROWS:" + json.dumps(backend_rows(args.backend_child, args.quick)))
         return
 
     repeat = 3 if args.quick else 16
@@ -668,25 +683,25 @@ def main():
     )
     rows += er
     print(f"stream: eeg-pencils end-to-end = {e_mbps:.2f} MB/s")
-    backend_rows = bench_backends(args.devices, args.quick)
-    rows += backend_rows
-    if backend_rows:
+    sharded_rows = bench_backends(args.devices, args.quick)
+    rows += sharded_rows
+    devices = f"{sharded_rows[0]['n_devices']} {'fake ' if FAKE_DEVICE_CHILD else ''}devices"
+    print(
+        f"backends ({devices}): sharded vs batched = "
+        f"{sharded_rows[0]['speedup_sharded_vs_batched']:.2f}x"
+    )
+    dist_rows = [
+        r
+        for r in sharded_rows
+        if r["bench"] == "dist_field" and r["path"].startswith("fused")
+    ]
+    for r in dist_rows:
+        kind = "uneven " if r["path"].endswith("-uneven") else ""
         print(
-            f"backends ({args.devices} fake devices): sharded vs batched = "
-            f"{backend_rows[0]['speedup_sharded_vs_batched']:.2f}x"
+            f"dist_field {kind}({devices}, shape "
+            f"{tuple(r['shape'])}, parity {r['parity']}): pencil-sharded vs "
+            f"fused single-device = {r['speedup_pencil_vs_fused']:.2f}x"
         )
-        dist_rows = [
-            r
-            for r in backend_rows
-            if r["bench"] == "dist_field" and r["path"].startswith("fused")
-        ]
-        for r in dist_rows:
-            kind = "uneven " if r["path"].endswith("-uneven") else ""
-            print(
-                f"dist_field {kind}({args.devices} fake devices, shape "
-                f"{tuple(r['shape'])}, parity {r['parity']}): pencil-sharded vs "
-                f"fused single-device = {r['speedup_pencil_vs_fused']:.2f}x"
-            )
 
     meta = {
         "backend": jax.default_backend(),

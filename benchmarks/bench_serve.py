@@ -47,6 +47,7 @@ import time
 import numpy as np
 
 from repro.core.temporal import TemporalConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve_ffcz import (
     add_fault_args,
     add_service_args,
@@ -225,6 +226,7 @@ def main():
     add_workload_args(ap)
     add_fault_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     n = args.requests_per_run or (12 if args.quick else 96)
     rates = [float(r) for r in args.arrival_rates.split(",") if r] or [20.0]

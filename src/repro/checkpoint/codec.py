@@ -156,7 +156,7 @@ class CheckpointCodec:
         del errs  # free the float32 error copies; tiles0 carries the state
 
         for (i, base_blob, tiles0, plan), (spat_t, freq_t) in zip(work, edits):
-            se, fe = self.engine.encode_pencils(spat_t, freq_t, tiles0, plan, codec="zlib")
+            se, fe, _settled = self.engine.encode_pencils(spat_t, freq_t, tiles0, plan, codec="zlib")
             se_b, fe_b = se.to_bytes(), fe.to_bytes()
             arr = arrays[i]
             header = struct.pack(

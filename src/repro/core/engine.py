@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
+from scipy import fft as host_fft
 
 from repro.coding.quantize import DEFAULT_QUANT_BITS
 from repro.core import blockwise
@@ -61,6 +62,8 @@ from repro.core.pocs import (
     _alternating_projection,
     alternating_projection,
 )
+from repro.kernels.rfft.ops import field_rfftn
+from repro.launch.mesh import make_mesh
 from repro.sharding import dist_fft
 from repro.sharding.dist_fft import ShardedField
 from repro.sharding.shardmap import shard_map
@@ -72,6 +75,12 @@ _BACKENDS = ("local", "batched", "sharded")
 # shared guarantee math (one home; FFCz re-exports for backward compat)
 
 
+#: Frequency excess, relative to the largest Delta, below which the float64
+#: polish may stop once it no longer shrinks (float64 FFT round-off sits
+#: near 1e-15 of Delta on 128^3 Nyx-like fields).
+POLISH_FLOOR_REL = 1e-12
+
+
 def polish_pocs_float64(eps, spat, freq, E, Delta, axes=None, max_iters: int = 30):
     """Exact (float64) POCS iterations to absorb float32 FFT round-off.
 
@@ -81,22 +90,42 @@ def polish_pocs_float64(eps, spat, freq, E, Delta, axes=None, max_iters: int = 3
     after the float32 loop are O(eps32 * ||delta||_inf), orders of magnitude
     below the bounds, so this converges in a handful of iterations and
     contributes negligibly to the edit payload.
+
+    It stops at the exact fixed point (no component outside the f-cube), or
+    once the largest frequency excess is below ``POLISH_FLOOR_REL * max
+    Delta`` and no longer shrinking: each round trip cuts the excess about
+    100x until the FFT's own float64 rounding sets a floor, and iterations
+    past that point only cycle on it.  The state it returns is the previous
+    iteration's, which ends on the s-cube projection, so the spatial bound
+    holds exactly either way.
+
+    Returns ``(eps, spat, freq, settled)``; ``settled`` is False when the
+    ``max_iters`` cap left a frequency excess above that floor.
+
+    The transforms are scipy's pocketfft on every host core (``workers=-1``):
+    each line is transformed alone, so the result does not depend on the
+    thread count, and a 512^3 field's polish no longer waits on one core.
     """
     axes = tuple(range(eps.ndim)) if axes is None else tuple(axes)
     s = [eps.shape[a] for a in axes]
-    for _ in range(max_iters):
-        delta = np.fft.rfftn(eps, axes=axes)
+    floor = POLISH_FLOOR_REL * float(np.max(Delta)) if np.size(Delta) else 0.0
+    prev = np.inf
+    for it in range(max_iters + 1):
+        delta = host_fft.rfftn(eps, axes=axes, workers=-1)
         re = np.clip(delta.real, -Delta, Delta)
         im = np.clip(delta.imag, -Delta, Delta)
-        clipped = re + 1j * im
-        if np.array_equal(clipped, delta):
+        disp = (re - delta.real) + 1j * (im - delta.imag)
+        excess = float(np.max(np.abs(disp))) if disp.size else 0.0
+        if excess == 0.0 or prev <= excess <= floor or it == max_iters:
             break
-        freq = freq + (clipped - delta)
-        eps_f = np.fft.irfftn(clipped, s=s, axes=axes)
+        prev = excess
+        freq = freq + disp
+        clipped = re + 1j * im
+        eps_f = host_fft.irfftn(clipped, s=s, axes=axes, workers=-1)
         eps_s = np.clip(eps_f, -E, E)
         spat = spat + (eps_s - eps_f)
         eps = eps_s
-    return eps, spat, freq
+    return eps, spat, freq, excess <= floor
 
 
 def _host_l2_norm(x32: np.ndarray) -> float:
@@ -289,7 +318,7 @@ class FieldExecuteHandle:
     def __init__(self, engine: "CorrectionEngine", raw, eps0, plan: FieldPlan):
         self._engine = engine
         self._raw = raw  # AlternatingProjectionResult of in-flight device arrays
-        self._eps0 = eps0  # the ShardedField when sharded, else None
+        self._eps0 = eps0  # the initial error (host array or ShardedField)
         self._plan = plan
         self._value: Optional[FieldResult] = None
         self._exc: Optional[FFCzError] = None
@@ -517,7 +546,7 @@ class CorrectionEngine:
     @property
     def mesh(self):
         if self._mesh is None:
-            self._mesh = jax.make_mesh((len(jax.devices()),), (self.axis,))
+            self._mesh = make_mesh((len(jax.devices()),), (self.axis,))
         return self._mesh
 
     # -- PLAN --------------------------------------------------------------
@@ -572,7 +601,7 @@ class CorrectionEngine:
         else:
             x32 = np.asarray(x, dtype=np.float32)
             x_dev = jnp.asarray(x32)
-            rfftn = jnp.fft.rfftn
+            rfftn = field_rfftn
         if cfg.pspec_rel is not None:
             # the padded sharded spectrum's pad rows are exactly zero, so the
             # grid max / floor / DC reductions below see the same values as
@@ -823,11 +852,10 @@ class CorrectionEngine:
             # device dispatch / allocation failures carry stage + disposition
             # (OOM -> "bisect") so serving loops can act without string-matching
             raise classify_exception(e, "execute") from e
-        return FieldExecuteHandle(self, res, eps0 if sharded else None, plan)
+        return FieldExecuteHandle(self, res, eps0, plan)
 
-    def _finalize_field(self, res, sharded_field, plan: FieldPlan) -> FieldResult:
+    def _finalize_field(self, res, eps0, plan: FieldPlan) -> FieldResult:
         """The fence + host half of EXECUTE (see :meth:`execute_field_async`)."""
-        sharded = sharded_field is not None
         try:
             # edit state -> host: this is the encode/serialization staging (the
             # single-device path stages identically); the float64 polish is a
@@ -839,24 +867,30 @@ class CorrectionEngine:
             jax.block_until_ready(res)
             spat = np.asarray(res.spat_edits, dtype=np.float64)
             freq = np.asarray(res.freq_edits, dtype=np.complex128)
-            eps_f = np.asarray(res.eps, dtype=np.float64)
         except (RuntimeError, MemoryError) as e:
             # an async device failure surfaces at the fence, not at dispatch
             raise classify_exception(e, "execute") from e
-        if sharded:
-            eps0 = sharded_field
+        if isinstance(eps0, ShardedField):
             spat = eps0.unpad_spatial(spat)
-            eps_f = eps0.unpad_spatial(eps_f)
             freq = eps0.unpad_freq(freq)
+            eps0 = eps0.to_host()
+        # The polish starts from the state the edit streams encode, rebuilt
+        # in float64: the device loop keeps eps == eps0 + IFFT(freq) + spat
+        # only as far as its own float32 transforms are accurate, and the
+        # decoder reconstructs from the edits, not from the device's eps.
+        eps_f = np.asarray(eps0, dtype=np.float64) + (
+            host_fft.irfftn(freq, s=plan.shape, axes=tuple(range(len(plan.shape))), workers=-1)
+            + spat
+        )
         E_pol = (
             plan.E_proj
             if plan.E_grid_proj is None
             else np.asarray(plan.E_grid_proj, dtype=np.float64)
         )
-        eps_f, spat, freq = polish_pocs_float64(
+        eps_f, spat, freq, settled = polish_pocs_float64(
             eps_f, spat, freq, E_pol, np.asarray(plan.Delta_proj, dtype=np.float64)
         )
-        converged = bool(res.converged)
+        converged = bool(res.converged) and settled
         final_violations = 0
         if not converged:
             # Surface non-convergence with an exact post-polish count: the
@@ -1166,19 +1200,21 @@ class CorrectionEngine:
         tiles0: np.ndarray,
         plan: PencilPlan,
         codec: str = "zlib",
-    ) -> Tuple[EncodedEdits, EncodedEdits]:
+    ) -> Tuple[EncodedEdits, EncodedEdits, bool]:
         """Polish + serialize one tensor's pencil edit streams.
 
         ``spat_t``/``freq_t`` are the device edit tiles from
         :meth:`correct`; ``tiles0`` the float64 tiling of the *initial*
         error (:meth:`tile_f64`).  The float64 polish reruns on the
         reconstructed loop state, then adaptive bit-widths are chosen per
-        worst-case pencil.
+        worst-case pencil.  The third value is the polish's ``settled`` flag
+        (see :func:`polish_pocs_float64`): False means the tensor is not
+        converged, whatever the device loop reported.
         """
         spat = np.asarray(spat_t, dtype=np.float64)
         freq = np.asarray(freq_t, dtype=np.complex128)
         eps_now = tiles0 + np.fft.irfft(freq, n=plan.block, axis=-1) + spat
-        _eps, spat, freq = polish_pocs_float64(
+        _eps, spat, freq, settled = polish_pocs_float64(
             eps_now, spat, freq, plan.E_proj, plan.Delta_proj, axes=(1,)
         )
         pair_w = np.asarray(rfft_pair_weights((plan.block,))).reshape(-1)
@@ -1192,7 +1228,7 @@ class CorrectionEngine:
             fe = encode_edits(freq, plan.Delta, m=m_f, codec=codec, half_spectrum=True)
         except (RuntimeError, MemoryError, OSError) as e:
             raise classify_exception(e, "encode") from e
-        return se, fe
+        return se, fe, settled
 
 
 @functools.lru_cache(maxsize=None)

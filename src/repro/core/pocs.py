@@ -56,6 +56,13 @@ pluggable through :mod:`repro.kernels.rfft`:
                 de-interleave — one pass over the data instead of
                 FFT-then-clip (interpret mode on CPU, Mosaic on TPU).
 
+On TPU, whose C2R is inaccurate
+(:func:`repro.kernels.rfft.ops.c2r_is_accurate`), ``"xla"`` runs XLA's
+transforms as separate per-axis passes — the passes of the slab-sharded
+``dist`` mode, in its order — with the last-axis C2R pass swapped for the
+pack trick between ``optimization_barrier`` s; ``"packed"`` keeps its
+whole-field inverse, also between barriers there.
+
 Packed/pallas trajectories differ from ``"xla"`` at float32-rounding level
 (the 1/N normalization and twiddle roundings sit elsewhere), so distributed
 parity for them is ``"bound"``, never ``"bitwise"`` — the dual-bound
@@ -224,9 +231,9 @@ def _alternating_projection(
     # Packed/pallas transforms need an even last axis; fall back statically
     # otherwise ("packed" -> the XLA transforms, "pallas" -> XLA transforms
     # + the fused fcube/scube projection kernels of the use_kernels path).
-    if fft_impl != "xla":
-        from repro.kernels.rfft import ops as _rfft_ops
+    from repro.kernels.rfft import ops as _rfft_ops
 
+    if fft_impl != "xla":
         _packed_ok = _rfft_ops.supports_packed(dist.gshape if dist is not None else shape)
     else:
         _packed_ok = False
@@ -265,13 +272,21 @@ def _alternating_projection(
             # so the rfft half-plane slice is exact
             Delta_r = Delta_r[..., : shape[-1] // 2 + 1]
         freq_shape = rfft_shape(shape)
-        fwd = lambda e: jnp.fft.rfftn(e).astype(cdtype)  # noqa: E731
         if _packed_ok:
             # the measured gap is the C2R inverse; the XLA forward (DUCC r2c,
             # already pack-trick fast) stays
-            inv = lambda d: _rfft_ops.packed_irfftn(d, shape).astype(eps0.dtype)  # noqa: E731
+            fwd = lambda e: jnp.fft.rfftn(e).astype(cdtype)  # noqa: E731
+            if _rfft_ops.c2r_is_accurate():
+                inv = lambda d: _rfft_ops.packed_irfftn(d, shape).astype(eps0.dtype)  # noqa: E731
+            else:
+                # isolated from the loop body, as in rfft ops.c2r_last
+                barrier = jax.lax.optimization_barrier
+                inv = lambda d: barrier(  # noqa: E731
+                    _rfft_ops.packed_irfftn(barrier(d), shape)
+                ).astype(eps0.dtype)
         else:
-            inv = lambda d: jnp.fft.irfftn(d, s=shape).astype(eps0.dtype)  # noqa: E731
+            fwd = lambda e: _rfft_ops.field_rfftn(e).astype(cdtype)  # noqa: E731
+            inv = lambda d: _rfft_ops.field_irfftn(d, shape).astype(eps0.dtype)  # noqa: E731
     else:
         weights = None
         freq_shape = shape

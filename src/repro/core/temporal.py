@@ -706,11 +706,11 @@ class TemporalCodec:
             )
             spat_t, freq_t = edits[0]
             warm_next = np.asarray(freq_t, dtype=np.complex64)
-            se, fe = self.engine.encode_pencils(spat_t, freq_t, tiles0, plan, codec="zlib")
+            se, fe, settled = self.engine.encode_pencils(spat_t, freq_t, tiles0, plan, codec="zlib")
             payload = _pencil_blob(data32.shape, base_blob, se, fe, plan, block)
             decoded = decode_pencil_blob(payload, self.base)
             iters = int(np.max(np.asarray(stats.iterations))) if np.asarray(stats.iterations).size else 0
-            conv = bool(np.all(np.asarray(stats.converged)))
+            conv = bool(np.all(np.asarray(stats.converged))) and settled
             return payload, decoded, warm_next, iters, conv
         run_cfg = dataclasses.replace(
             cfg, E_abs=float(E_abs), E_rel=None, Delta_abs=float(Delta_abs),

@@ -27,10 +27,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import LANES, count_shape, count_spec, store_count
+
 # VPU-aligned tile: (rows, 128) float32.  9 live buffers per grid step
 # (re/im in, delta, weight, re/im out, edit re/im, viol) * 256*128*4B ~ 1.1 MiB << VMEM.
 BLOCK_ROWS = 256
-LANES = 128
 
 
 def _fcube_kernel(
@@ -55,7 +56,7 @@ def _fcube_kernel(
     viol = ((jnp.abs(re) > dt) | (jnp.abs(im) > dt)).astype(jnp.int32) * w
     # dtype pinned: under jax_enable_x64 a bare sum promotes to int64 and
     # the store into the int32 out ref fails at trace time
-    viol_ref[0] = jnp.sum(viol, dtype=jnp.int32)
+    store_count(viol_ref, jnp.sum(viol, dtype=jnp.int32))
 
 
 @functools.partial(
@@ -81,7 +82,9 @@ def fcube_pallas(
     ``check_slack`` is a (1, 1) absolute convergence allowance added on top
     of the relative ``check_tol`` (defaults to 0).
 
-    Returns (clipped_re, clipped_im, edit_re, edit_im, viol_per_block).
+    Returns (clipped_re, clipped_im, edit_re, edit_im, viol) where ``viol``
+    holds one count tile per grid step (see ``tiling.count_spec``); its sum
+    is the violation count.
     """
     rows = delta_re.shape[0]
     assert delta_re.shape[1] == LANES and rows % block_rows == 0
@@ -92,9 +95,9 @@ def fcube_pallas(
     scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     delta_spec = data_spec if pointwise else scalar_spec
     weight_spec = data_spec if weighted else scalar_spec
-    out_specs = [data_spec] * 4 + [pl.BlockSpec((1,), lambda i: (i,))]
+    out_specs = [data_spec] * 4 + [count_spec()]
     out_shapes = [jax.ShapeDtypeStruct((rows, LANES), delta_re.dtype) for _ in range(4)] + [
-        jax.ShapeDtypeStruct(grid, jnp.int32)
+        count_shape(grid[0])
     ]
     return pl.pallas_call(
         functools.partial(_fcube_kernel, check_tol=check_tol),

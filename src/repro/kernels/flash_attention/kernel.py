@@ -32,11 +32,6 @@ DEFAULT_BLOCK_K = 256
 _NEG_INF = -1e30
 _LANES = 128
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 def _flash_kernel(
     q_ref,  # (1, 1, bq, d)
@@ -150,7 +145,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -8,10 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
-
-def _is_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+from repro.kernels.tiling import is_cpu as _is_cpu
 
 
 @functools.partial(

@@ -8,10 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.quantize.kernel import BLOCK_ROWS, LANES, quantize_pallas
-
-
-def _is_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+from repro.kernels.tiling import is_cpu as _is_cpu
 
 
 @functools.partial(jax.jit, static_argnames=("m", "block_rows", "interpret"))

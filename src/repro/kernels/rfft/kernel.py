@@ -40,11 +40,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import LANES, count_shape, count_spec, store_count
+
 # VPU-aligned tile, shared with the fcube/scube kernels.  The forward
 # epilogue holds 17 live (rows, 128) float32 planes per grid step:
 # 256*128*4B * 17 ~ 2.2 MiB << VMEM.
 BLOCK_ROWS = 256
-LANES = 128
 
 
 def _rfft_fwd_epilogue_kernel(
@@ -85,7 +86,7 @@ def _rfft_fwd_epilogue_kernel(
     viol = ((jnp.abs(xr) > dt) | (jnp.abs(xi) > dt)).astype(jnp.int32) * pw_ref[...]
     # dtype pinned: under jax_enable_x64 a bare sum promotes to int64 and
     # the store into the int32 out ref fails at trace time
-    viol_ref[0] = jnp.sum(viol, dtype=jnp.int32)
+    store_count(viol_ref, jnp.sum(viol, dtype=jnp.int32))
 
 
 @functools.partial(
@@ -116,8 +117,9 @@ def rfft_fwd_epilogue_pallas(
     ``weight`` the int32 pair-weight plane, ``check_slack`` a (1, 1) absolute
     convergence allowance.
 
-    Returns ``(clip_re, clip_im, edit_re, edit_im, z_re, z_im,
-    viol_per_block)``.
+    Returns ``(clip_re, clip_im, edit_re, edit_im, z_re, z_im, viol)`` where
+    ``viol`` holds one count tile per grid step (see ``tiling.count_spec``);
+    its sum is the violation count.
     """
     rows = delta_re.shape[0]
     assert delta_re.shape[1] == LANES and rows % block_rows == 0
@@ -125,9 +127,9 @@ def rfft_fwd_epilogue_pallas(
     data_spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     delta_spec = data_spec if pointwise else scalar_spec
-    out_specs = [data_spec] * 6 + [pl.BlockSpec((1,), lambda i: (i,))]
+    out_specs = [data_spec] * 6 + [count_spec()]
     out_shapes = [jax.ShapeDtypeStruct((rows, LANES), delta_re.dtype) for _ in range(6)] + [
-        jax.ShapeDtypeStruct(grid, jnp.int32)
+        count_shape(grid[0])
     ]
     return pl.pallas_call(
         functools.partial(_rfft_fwd_epilogue_kernel, check_tol=check_tol),

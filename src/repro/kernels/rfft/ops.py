@@ -172,6 +172,72 @@ def packed_irfft(X: jnp.ndarray, n: int) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# per-axis transforms (fft_impl="xla" where the backend's C2R is inaccurate)
+
+
+def c2r_is_accurate() -> bool:
+    """Whether XLA's C2R transform (``jnp.fft.irfft``/``irfftn``) is accurate.
+
+    False on TPU: on a v5e (JAX 0.9, libtpu 0.0.34) it returned outputs off
+    by up to ~30% of their largest value at lengths 256 and 512, while the
+    R2C and C2C transforms stayed at float32 accuracy.  Where it is False,
+    the whole-field transforms (:func:`field_rfftn`, :func:`field_irfftn`)
+    and the slab-sharded ones in :mod:`repro.sharding.dist_fft` run the same
+    per-axis passes, ending in the pack-trick C2R pass of :func:`c2r_last`.
+    """
+    return jax.default_backend() != "tpu"
+
+
+def c2r_last(X: jnp.ndarray, n: int, packed: bool = False) -> jnp.ndarray:
+    """The last-axis C2R pass of a per-axis inverse (``irfft`` to length ``n``).
+
+    The pack trick (:func:`packed_irfft`) where ``packed`` asks for it or
+    where XLA's C2R is inaccurate; ``jnp.fft.irfft`` otherwise and for odd
+    ``n``, which has no pack trick.  On a backend with an inaccurate C2R the
+    pass runs between ``optimization_barrier`` s: on a v5e, the pack-trick
+    inverse fused with its neighbours in the POCS loop body drifted by ~E
+    per run at 512^3, and isolated it did not.
+    """
+    if n % 2 or n < 2 or (not packed and c2r_is_accurate()):
+        return jnp.fft.irfft(X, n=n, axis=-1)
+    if c2r_is_accurate():
+        return packed_irfft(X, n)
+    barrier = jax.lax.optimization_barrier
+    return barrier(packed_irfft(barrier(X), n))
+
+
+def rfftn_passes(x: jnp.ndarray) -> jnp.ndarray:
+    """``rfftn`` as separate passes: r2c along the last axis, then c2c along
+    axis 0, 1, ... — the pass order of the slab-sharded transform
+    (:func:`repro.sharding.dist_fft.rfftn_local`)."""
+    r = jnp.fft.rfft(x, axis=-1)
+    for ax in range(x.ndim - 1):
+        r = jnp.fft.fft(r, axis=ax)
+    return r
+
+
+def irfftn_passes(X: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
+    """Inverse of :func:`rfftn_passes`: c2c along axis 0, 1, ..., then
+    :func:`c2r_last` (the slab-sharded inverse's order)."""
+    for ax in range(len(shape) - 1):
+        X = jnp.fft.ifft(X, axis=ax)
+    return c2r_last(X, shape[-1])
+
+
+def field_rfftn(x: jnp.ndarray) -> jnp.ndarray:
+    """The whole-field forward of ``fft_impl="xla"`` and of PLAN:
+    ``jnp.fft.rfftn``, or :func:`rfftn_passes` where XLA's C2R is inaccurate,
+    so that a single-device field runs the passes of a slab-sharded one."""
+    return jnp.fft.rfftn(x) if c2r_is_accurate() else rfftn_passes(x)
+
+
+def field_irfftn(X: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
+    """The whole-field inverse of ``fft_impl="xla"``: ``jnp.fft.irfftn``, or
+    :func:`irfftn_passes` where XLA's C2R is inaccurate."""
+    return jnp.fft.irfftn(X, s=shape) if c2r_is_accurate() else irfftn_passes(X, shape)
+
+
+# ---------------------------------------------------------------------------
 # fused Pallas epilogues (fft_impl="pallas"); plane tiling + padding contract
 # shared with the fcube/scube suites via repro.kernels.tiling
 

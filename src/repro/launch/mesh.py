@@ -20,12 +20,19 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (elastic re-planning, tests)."""
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    """Mesh whose axes are all ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to explicit axis types, under which the
+    compiler refuses the slab code's slices of sharded dims and the rule
+    tables' repeated axis names.  Every mesh in the repo is built here.
+    """
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def make_host_mesh(model_parallel: Optional[int] = None):
@@ -33,7 +40,7 @@ def make_host_mesh(model_parallel: Optional[int] = None):
     n = len(jax.devices())
     mp = model_parallel or 1
     assert n % mp == 0
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

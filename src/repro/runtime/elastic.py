@@ -14,7 +14,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax
+from repro.launch.mesh import make_mesh
 
 
 def plan_mesh_shape(n_devices: int, preferred_model: int = 16) -> Tuple[Tuple[int, int], Tuple[str, str]]:
@@ -27,7 +27,7 @@ def plan_mesh_shape(n_devices: int, preferred_model: int = 16) -> Tuple[Tuple[in
 
 def replan_mesh(n_devices: int, preferred_model: int = 16):
     shape, axes = plan_mesh_shape(n_devices, preferred_model)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def survivors_after_pod_loss(total: int = 512, pods: int = 2, lost_pods: int = 1) -> int:

@@ -174,6 +174,7 @@ class RequestStats:
     fft_impl: Optional[str] = None  # transform the final attempt ran with
     converged: Optional[bool] = None
     final_violations: int = 0
+    iterations: Optional[int] = None  # POCS iterations of the final attempt
     # Derived-quantity shell recheck (cfg.verify_pspec, field requests in
     # pspec mode): max live-shell |P_hat(k)/P(k) - 1| of the decoded blob.
     pspec_shell_err: Optional[float] = None
@@ -206,6 +207,7 @@ class _Request:
     fft_impl: Optional[str] = None
     converged: Optional[bool] = None
     final_violations: int = 0
+    iterations: Optional[int] = None
     pspec_shell_err: Optional[float] = None
 
     def elapsed(self, now: float) -> float:
@@ -691,6 +693,7 @@ class FFCzService:
             fft_impl=req.fft_impl,
             converged=req.converged,
             final_violations=req.final_violations,
+            iterations=req.iterations,
             pspec_shell_err=req.pspec_shell_err,
         )
 
@@ -825,6 +828,7 @@ class FFCzService:
             )
             req.converged = bool(result.converged)
             req.final_violations = int(result.final_violations)
+            req.iterations = int(result.iterations)
 
             def _encode():
                 self._fire("codec", req.uid)
@@ -1055,6 +1059,7 @@ class FFCzService:
         self._tick("execute_s", t0)
 
         conv = np.asarray(stats.converged)
+        iters = np.asarray(stats.iterations)
         out = []
         t0 = self._clock()
         try:
@@ -1062,13 +1067,15 @@ class FFCzService:
                 zip(work, edits)
             ):
                 req.converged = bool(conv[j]) if conv.size else True
+                req.iterations = int(iters[j]) if iters.size else 0
                 try:
 
                     def _encode(s=spat_t, f=freq_t, t=tiles0, p=plan, r=req):
                         self._fire("codec", r.uid)
                         return self.engine.encode_pencils(s, f, t, p, codec="zlib")
 
-                    se, fe = self._attempt(req, "encode", _encode)
+                    se, fe, settled = self._attempt(req, "encode", _encode)
+                    req.converged = req.converged and settled
                     x = np.asarray(req.payload)
                     payload = _pencil_blob(x.shape, base_blob, se, fe, plan, self.config.block)
                     out.append(self._complete(req, payload))

@@ -24,9 +24,11 @@ step of the sharded power spectrum — do mask pad rows explicitly).
 Bitwise discipline (the PR 2 parity bar, extended to whole fields): the
 single-device ``jnp.fft.rfftn`` computes its passes in a fixed axis order —
 r2c along the *last* axis, then c2c along axis 0, then axis 1 (verified
-empirically on the CPU and TPU DUCC/FFT lowering; ``tests/test_dist_fft.py``
-gates it).  The distributed transform applies the *same per-axis passes in
-the same order*, transposing between them, and each local pass is
+empirically on the CPU DUCC lowering; ``tests/test_dist_fft.py`` gates it).
+On TPU the single-device whole-field path runs these passes explicitly
+(:func:`repro.kernels.rfft.ops.field_rfftn`), since its C2R is inaccurate.
+The distributed transform applies the *same per-axis passes in the same
+order*, transposing between them, and each local pass is
 batch-invariant (a slab's rows — or a chunk of its last axis — transform
 identically whatever the slab or chunk count; the conformance suite gates
 this).  ``all_to_all`` moves bits untouched, padding only ever inserts and
@@ -92,6 +94,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.sharding.shardmap import shard_map
 
 #: Default number of last-axis chunks each 3-D all_to_all+FFT pair is split
@@ -329,21 +332,20 @@ def rfftn_local(block: jnp.ndarray, spec: DistSpec) -> jnp.ndarray:
 
 
 def _c2r_last(p: jnp.ndarray, n: int, fft_impl: str) -> jnp.ndarray:
-    """The local last-axis C2R pass, with the pack-trick fast path.
+    """The local last-axis C2R pass: :func:`repro.kernels.rfft.ops.c2r_last`.
 
     ``fft_impl="packed"`` swaps XLA's C2R custom call (the measured slow
-    half of the loop) for :func:`repro.kernels.rfft.ops.packed_irfft` — a
-    per-line transform, so it composes with the pencil decomposition
-    unchanged (every line it sees is a full half-spectrum of a real line).
-    Odd last axes fall back to XLA.  The packed pass rounds differently
-    from the fused single-device inverse, so distributed parity under it is
-    ``"bound"``, never ``"bitwise"``.
+    half of the loop) for the pack-trick pass — a per-line transform, so it
+    composes with the pencil decomposition unchanged (every line it sees is
+    a full half-spectrum of a real line).  On CPU the packed pass rounds
+    differently from the fused single-device inverse, so distributed parity
+    under it is ``"bound"``, never ``"bitwise"``.  Where XLA's C2R is
+    inaccurate (TPU) ``"xla"`` takes the pack-trick pass too, exactly as the
+    single-device per-axis inverse does.
     """
-    if fft_impl == "packed" and n % 2 == 0 and n >= 2:
-        from repro.kernels.rfft import ops as rfft_ops
+    from repro.kernels.rfft import ops as rfft_ops
 
-        return rfft_ops.packed_irfft(p, n)
-    return jnp.fft.irfft(p, n=n, axis=p.ndim - 1)
+    return rfft_ops.c2r_last(p, n, packed=fft_impl == "packed")
 
 
 def irfftn_local(
@@ -466,7 +468,7 @@ class ShardedField:
     ) -> "ShardedField":
         """Shard a host array over ``mesh[axis_name]`` (default: all devices)."""
         if mesh is None:
-            mesh = jax.make_mesh((len(jax.devices()),), (axis_name,))
+            mesh = make_mesh((len(jax.devices()),), (axis_name,))
         return cls(
             x, mesh, axis_name, parity, overlap_chunks, strict_bitwise=strict_bitwise
         )

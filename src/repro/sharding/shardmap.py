@@ -1,11 +1,8 @@
-"""Version-portable ``shard_map``.
+"""``shard_map`` with the replication check off by default.
 
-JAX moved ``shard_map`` from ``jax.experimental.shard_map`` (where the
-replication check is spelled ``check_rep``) to ``jax.shard_map`` (where it is
-``check_vma``).  Every manual-collective region in this repo — the pipeline
-schedule, the compressed all-reduce, and the CorrectionEngine's sharded
-pencil backend — goes through this one shim so the repo runs on both API
-generations.
+Every manual-collective region in this repo — the pipeline schedule, the
+compressed all-reduce, and the CorrectionEngine's sharded pencil backend —
+goes through this one wrapper.
 """
 
 from __future__ import annotations
@@ -20,12 +17,6 @@ def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
     checker — the manual regions in this repo use collectives whose
     replication the checker cannot always infer.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )

@@ -37,3 +37,19 @@ def pytest_configure(config):
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def inaccurate_c2r(monkeypatch):
+    """Run as on TPU, whose C2R is inaccurate: the probe says so, and every
+    cached trace is dropped before and after (the probe is read at trace
+    time)."""
+    import jax
+
+    from repro.kernels.rfft import ops as rfft_ops
+
+    jax.clear_caches()
+    monkeypatch.setattr(rfft_ops, "c2r_is_accurate", lambda: False)
+    yield
+    monkeypatch.undo()
+    jax.clear_caches()

@@ -219,7 +219,8 @@ def dist_results(request):
         env=env,
         timeout=900,
     )
-    assert proc.returncode == 0, proc.stderr[-3000:]
+    if proc.returncode != 0:  # the child's whole stderr, not a tail
+        pytest.fail(f"child exited {proc.returncode}:\n{proc.stderr}", pytrace=False)
     line = [ln for ln in proc.stdout.splitlines() if ln.startswith("RESULTS:")][0]
     return json.loads(line[len("RESULTS:") :]), request.param
 
@@ -377,3 +378,32 @@ class TestShapeClassification:
         assert padded_spatial_shape((32, 16, 12), 8) == (32, 16, 12)
         assert padded_freq_shape((30, 14, 10), 8) == (32, 14, 6)
         assert padded_freq_shape((30, 48), 8) == (30, 32)  # H=25 -> 32
+
+
+class TestInaccurateC2R:
+    @pytest.mark.parametrize("shape", [(16, 16, 16), (12, 10, 16), (32, 62)])
+    def test_payload_matches_one_device_mesh(self, shape, inaccurate_c2r):
+        """Where XLA's C2R is inaccurate (TPU), the single-device field runs
+        the slab transform's per-axis passes, so ``FFCz.compress`` of the
+        field and of its one-device-mesh ``ShardedField`` write the same
+        payload, and the decoded field holds both stored bounds."""
+        import jax
+        import numpy as np
+
+        from repro.compressors import get_compressor
+        from repro.core.ffcz import FFCz, FFCzConfig, ShardedField
+        from repro.launch.mesh import make_mesh
+
+        rng = np.random.default_rng(3)
+        x = np.exp(rng.standard_normal(shape)).astype(np.float32)
+        # a tight Delta, so the loop projects rather than only checking
+        codec = FFCz(get_compressor("szlike"), FFCzConfig(E_rel=1e-2, Delta_rel=1e-4))
+        mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
+        one = codec.compress(x)
+        sharded = codec.compress(ShardedField.shard(x, mesh))
+        assert one.stats.iterations > 1
+        assert one.payload_bytes() == sharded.payload_bytes()
+        err = codec.decompress(one).astype(np.float64) - x
+        d = np.fft.rfftn(err)
+        assert np.abs(err).max() <= one.E
+        assert max(np.abs(d.real).max(), np.abs(d.imag).max()) <= one.Delta_scalar

@@ -23,7 +23,8 @@ from repro.optim.grad_compress import compressed_psum
 from repro.sharding.rules import batch_pspec, cache_pspecs, param_pspecs, to_shardings
 
 results = {}
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_smoke_config("qwen2-0.5b")
 bundle = build_model(cfg)
 opt = AdamW(warmup_steps=2)
@@ -78,7 +79,8 @@ def dist_results():
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True, env=env, timeout=600
     )
-    assert proc.returncode == 0, proc.stderr[-3000:]
+    if proc.returncode != 0:  # the child's whole stderr, not a tail
+        pytest.fail(f"child exited {proc.returncode}:\n{proc.stderr}", pytrace=False)
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULTS:")][0]
     return json.loads(line[len("RESULTS:"):])
 

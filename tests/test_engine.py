@@ -71,7 +71,8 @@ def sharded_results():
     proc = subprocess.run(
         [sys.executable, "-c", _SHARDED_SCRIPT], capture_output=True, text=True, env=env, timeout=600
     )
-    assert proc.returncode == 0, proc.stderr[-3000:]
+    if proc.returncode != 0:  # the child's whole stderr, not a tail
+        pytest.fail(f"child exited {proc.returncode}:\n{proc.stderr}", pytrace=False)
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULTS:")][0]
     return json.loads(line[len("RESULTS:"):])
 
@@ -297,3 +298,30 @@ class TestBlobWireFormat:
         clean = open(os.path.join(_DATA, "padfree_v1_blob.bin"), "rb").read()
         with pytest.raises(ValueError, match="pad-metadata|corrupt"):
             FFCzBlob.from_bytes(clean + b"JUNKJUNKJUNK")
+
+
+class TestFloat64Polish:
+    def test_stops_on_the_roundoff_floor(self, nyx, monkeypatch):
+        """The polish leaves its loop once the frequency excess stops
+        shrinking at float64 round-off, with the spatial bound exact and the
+        excess under the floor -- far fewer round trips than its cap."""
+        from repro.core import engine as engine_mod
+
+        seen = []
+        real = engine_mod.polish_pocs_float64
+        monkeypatch.setattr(
+            engine_mod, "polish_pocs_float64", lambda *a: seen.append(a) or real(*a)
+        )
+        cfg = FFCzConfig(E_rel=1e-3, Delta_rel=1e-3, verify=False)
+        FFCz(get_compressor("szlike"), cfg).compress(nyx)
+        eps0, spat0, freq0, E, Delta = seen[0]
+
+        calls = []
+        rfftn = np.fft.rfftn
+        monkeypatch.setattr(np.fft, "rfftn", lambda *a, **k: calls.append(1) or rfftn(*a, **k))
+        eps, _spat, _freq, settled = real(eps0, spat0, freq0, E, Delta)
+        assert settled and len(calls) < 15
+        d = rfftn(eps)
+        excess = np.max(np.maximum(np.abs(d.real), np.abs(d.imag))) - Delta
+        assert excess <= engine_mod.POLISH_FLOOR_REL * Delta
+        assert np.abs(eps).max() <= E

@@ -206,7 +206,7 @@ class TestUnpackSclipKernel:
         from repro.core.pocs import alternating_projection
 
         x = (rng.standard_normal((8, 16)) * 0.04).astype(np.float32)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             r = alternating_projection(jnp.asarray(x), 0.05, 0.4, max_iters=20, fft_impl="pallas")
             assert bool(r.converged)
             r = alternating_projection(jnp.asarray(x), 0.05, 0.4, max_iters=20, use_kernels=True)

@@ -19,7 +19,8 @@ import json
 import jax, jax.numpy as jnp, numpy as np
 from repro.sharding.pipeline import pipeline_apply
 
-mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("pipe", "data"))
 S, LPS, M, MB, D = 4, 2, 6, 3, 16  # stages, layers/stage, microbatches, mb size, width
 rng = np.random.default_rng(0)
 Ws = jnp.asarray(rng.standard_normal((S, LPS, D, D)) * 0.3, dtype=jnp.float32)
@@ -60,7 +61,8 @@ class TestPipeline:
         proc = subprocess.run(
             [sys.executable, "-c", _SCRIPT], capture_output=True, text=True, env=env, timeout=600
         )
-        assert proc.returncode == 0, proc.stderr[-3000:]
+        if proc.returncode != 0:  # the child's whole stderr, not a tail
+            pytest.fail(f"child exited {proc.returncode}:\n{proc.stderr}", pytrace=False)
         line = [l for l in proc.stdout.splitlines() if l.startswith("RESULTS:")][0]
         res = json.loads(line[len("RESULTS:"):])
         assert res["err"] < 1e-5, res

@@ -362,3 +362,85 @@ class TestCheckEveryCadence:
         )
         assert not bool(r.converged)
         assert int(r.final_violations) > 0
+
+
+def _one_device_dist_loop(eps0, E, Delta, fft_impl):
+    """The slab-sharded ``dist`` loop on a one-device mesh (same passes as
+    a slab of any mesh, with trivial transposes)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import pocs
+    from repro.launch.mesh import make_mesh
+    from repro.sharding import dist_fft
+    from repro.sharding.shardmap import shard_map
+
+    spec = dist_fft.DistSpec("data", eps0.shape, 1)
+    fspec = dist_fft.freq_partition_spec(eps0.ndim, "data")
+    out = pocs.AlternatingProjectionResult(P("data"), P("data"), fspec, P(), P(), P())
+    run = shard_map(
+        lambda e: pocs._alternating_projection(
+            e, E, Delta, max_iters=500, dist=spec, fft_impl=fft_impl
+        ),
+        mesh=make_mesh((1,), ("data",), devices=jax.devices()[:1]),
+        in_specs=P("data"),
+        out_specs=out,
+    )
+    return jax.jit(run)(jnp.asarray(eps0))
+
+
+class TestTpuInverse:
+    @pytest.mark.parametrize("shape", [(32, 32), (12, 10, 16), (31, 17)])
+    def test_xla_without_accurate_c2r_is_the_packed_loop(self, shape, rng, inaccurate_c2r):
+        """Where the backend's C2R is inaccurate (TPU), ``fft_impl="xla"``
+        runs per-axis passes ending in the pack-trick C2R pass: the same
+        trajectory as the slab-sharded loop bit for bit, with both bounds
+        held.  Odd last axes keep XLA's own C2R pass, as there is no pack
+        trick for them."""
+        import jax
+
+        from repro.core import pocs
+
+        E = 0.1
+        eps0 = np.clip(rng.standard_normal(shape) * 0.05, -E, E).astype(np.float32)
+        Delta = 0.4 * np.abs(np.fft.rfftn(eps0)).max()
+        r = jax.jit(lambda e: pocs._alternating_projection(e, E, Delta, max_iters=500))(
+            jnp.asarray(eps0)
+        )
+        d = _one_device_dist_loop(eps0, E, Delta, "xla")
+        for field in ("eps", "spat_edits", "freq_edits"):
+            assert np.array_equal(np.asarray(getattr(r, field)), np.asarray(getattr(d, field)))
+        assert int(r.iterations) == int(d.iterations)
+        assert bool(r.converged)
+        eps = np.asarray(r.eps, np.float64)
+        assert np.abs(eps).max() <= np.float32(E)
+        f = np.fft.rfftn(eps)
+        assert max(np.abs(f.real).max(), np.abs(f.imag).max()) <= Delta * (1 + 1e-4)
+
+    @pytest.mark.parametrize("shape", [(64,), (31, 17), (12, 10, 16)])
+    def test_per_axis_transforms_are_accurate(self, shape, rng, inaccurate_c2r):
+        """The per-axis forward and inverse stay at float32 accuracy against
+        float64 numpy, and the inverse inverts the forward."""
+        from repro.kernels.rfft import ops as rfft_ops
+
+        x = rng.standard_normal(shape).astype(np.float32)
+        X = rfft_ops.field_rfftn(jnp.asarray(x))
+        ref = np.fft.rfftn(x.astype(np.float64))
+        assert np.abs(np.asarray(X) - ref).max() <= 1e-5 * np.abs(ref).max()
+        y = np.asarray(rfft_ops.field_irfftn(X, shape))
+        assert y.shape == shape
+        assert np.abs(y - x).max() <= 1e-5 * np.abs(x).max()
+
+    @pytest.mark.parametrize("n", [16, 15])
+    def test_c2r_last_takes_the_pack_trick_only_where_needed(self, n, rng, monkeypatch):
+        """Asked for (``packed``) or forced by an inaccurate C2R, an even
+        line takes the pack trick; odd lines always keep XLA's C2R."""
+        from repro.kernels.rfft import ops as rfft_ops
+
+        X = jnp.asarray(np.fft.rfft(rng.standard_normal((4, n))).astype(np.complex64))
+        xla = np.asarray(jnp.fft.irfft(X, n=n, axis=-1))
+        packed = np.asarray(rfft_ops.packed_irfft(X, n)) if n % 2 == 0 else xla
+        assert np.array_equal(np.asarray(rfft_ops.c2r_last(X, n)), xla)
+        assert np.array_equal(np.asarray(rfft_ops.c2r_last(X, n, packed=True)), packed)
+        monkeypatch.setattr(rfft_ops, "c2r_is_accurate", lambda: False)
+        assert np.array_equal(np.asarray(rfft_ops.c2r_last(X, n)), packed)
