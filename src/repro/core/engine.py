@@ -62,6 +62,7 @@ from repro.core.pocs import (
     _alternating_projection,
     alternating_projection,
 )
+from repro.core.spans import span
 from repro.kernels.rfft.ops import field_rfftn
 from repro.launch.mesh import make_mesh
 from repro.sharding import dist_fft
@@ -105,6 +106,8 @@ def polish_pocs_float64(eps, spat, freq, E, Delta, axes=None, max_iters: int = 3
     The transforms are scipy's pocketfft on every host core (``workers=-1``):
     each line is transformed alone, so the result does not depend on the
     thread count, and a 512^3 field's polish no longer waits on one core.
+    Each round trip past the check (one ``irfftn`` and its clips) is one
+    ``ffcz.polish.round`` span.
     """
     axes = tuple(range(eps.ndim)) if axes is None else tuple(axes)
     s = [eps.shape[a] for a in axes]
@@ -119,12 +122,13 @@ def polish_pocs_float64(eps, spat, freq, E, Delta, axes=None, max_iters: int = 3
         if excess == 0.0 or prev <= excess <= floor or it == max_iters:
             break
         prev = excess
-        freq = freq + disp
-        clipped = re + 1j * im
-        eps_f = host_fft.irfftn(clipped, s=s, axes=axes, workers=-1)
-        eps_s = np.clip(eps_f, -E, E)
-        spat = spat + (eps_s - eps_f)
-        eps = eps_s
+        with span("ffcz.polish.round"):
+            freq = freq + disp
+            clipped = re + 1j * im
+            eps_f = host_fft.irfftn(clipped, s=s, axes=axes, workers=-1)
+            eps_s = np.clip(eps_f, -E, E)
+            spat = spat + (eps_s - eps_f)
+            eps = eps_s
     return eps, spat, freq, excess <= floor
 
 
@@ -363,7 +367,8 @@ class PencilBatchHandle:
             raise self._exc
         if self._value is None:
             try:
-                res, stats = jax.block_until_ready((self._raw, self._stats))
+                with span("ffcz.fence"):
+                    res, stats = jax.block_until_ready((self._raw, self._stats))
                 corrected, edits = [], []
                 offset = 0
                 for (shape, dtype), nb, pad in zip(self._specs, self._counts, self._pads):
@@ -405,7 +410,8 @@ class _FenceHandle:
             raise self._exc
         if not self._fenced:
             try:
-                jax.block_until_ready(self._value)
+                with span("ffcz.fence"):
+                    jax.block_until_ready(self._value)
                 self._fenced = True
             except (RuntimeError, MemoryError) as e:
                 self._exc = classify_exception(e, "execute")
@@ -855,54 +861,63 @@ class CorrectionEngine:
         return FieldExecuteHandle(self, res, eps0, plan)
 
     def _finalize_field(self, res, eps0, plan: FieldPlan) -> FieldResult:
-        """The fence + host half of EXECUTE (see :meth:`execute_field_async`)."""
+        """The fence + host half of EXECUTE (see :meth:`execute_field_async`):
+        spans ``ffcz.fence``, ``ffcz.fetch`` and ``ffcz.polish``."""
+        # edit state -> host: this is the encode/serialization staging (the
+        # single-device path stages identically); the float64 polish is a
+        # handful of host FFT round trips on the O(residual) edit state.
+        # Sharded state arrives in the padded device layout — slab-pad
+        # rows/columns are exactly zero; slicing them away here restores the
+        # single-device shapes (and values, bitwise on "bitwise"-parity
+        # shapes) before the polish and encode stages.
         try:
-            # edit state -> host: this is the encode/serialization staging (the
-            # single-device path stages identically); the float64 polish is a
-            # handful of host FFT round trips on the O(residual) edit state.
-            # Sharded state arrives in the padded device layout — slab-pad
-            # rows/columns are exactly zero; slicing them away here restores the
-            # single-device shapes (and values, bitwise on "bitwise"-parity
-            # shapes) before the polish and encode stages.
-            jax.block_until_ready(res)
-            spat = np.asarray(res.spat_edits, dtype=np.float64)
-            freq = np.asarray(res.freq_edits, dtype=np.complex128)
+            with span("ffcz.fence"):
+                jax.block_until_ready(res)
         except (RuntimeError, MemoryError) as e:
             # an async device failure surfaces at the fence, not at dispatch
             raise classify_exception(e, "execute") from e
-        if isinstance(eps0, ShardedField):
-            spat = eps0.unpad_spatial(spat)
-            freq = eps0.unpad_freq(freq)
-            eps0 = eps0.to_host()
-        # The polish starts from the state the edit streams encode, rebuilt
-        # in float64: the device loop keeps eps == eps0 + IFFT(freq) + spat
-        # only as far as its own float32 transforms are accurate, and the
-        # decoder reconstructs from the edits, not from the device's eps.
-        eps_f = np.asarray(eps0, dtype=np.float64) + (
-            host_fft.irfftn(freq, s=plan.shape, axes=tuple(range(len(plan.shape))), workers=-1)
-            + spat
-        )
-        E_pol = (
-            plan.E_proj
-            if plan.E_grid_proj is None
-            else np.asarray(plan.E_grid_proj, dtype=np.float64)
-        )
-        eps_f, spat, freq, settled = polish_pocs_float64(
-            eps_f, spat, freq, E_pol, np.asarray(plan.Delta_proj, dtype=np.float64)
-        )
-        converged = bool(res.converged) and settled
-        final_violations = 0
-        if not converged:
-            # Surface non-convergence with an exact post-polish count: the
-            # float32 loop's exit count may overstate what the float64 polish
-            # could not absorb.  Pair weights keep full-spectrum semantics,
-            # matching the loop's own violation accounting.  (Converged runs
-            # skip the extra host rfftn — the default path pays nothing.)
-            d = np.fft.rfftn(eps_f)
-            tol = np.asarray(plan.Delta_proj, dtype=np.float64)
-            bad = (np.abs(d.real) > tol) | (np.abs(d.imag) > tol)
-            w = np.broadcast_to(np.asarray(rfft_pair_weights(plan.shape)), bad.shape)
-            final_violations = int(np.sum(w * bad))
+        with span("ffcz.fetch"):
+            try:
+                spat = np.asarray(res.spat_edits, dtype=np.float64)
+                freq = np.asarray(res.freq_edits, dtype=np.complex128)
+            except (RuntimeError, MemoryError) as e:
+                raise classify_exception(e, "execute") from e
+            if isinstance(eps0, ShardedField):
+                spat = eps0.unpad_spatial(spat)
+                freq = eps0.unpad_freq(freq)
+                eps0 = eps0.to_host()
+        with span("ffcz.polish"):
+            # The polish starts from the state the edit streams encode,
+            # rebuilt in float64: the device loop keeps eps == eps0 +
+            # IFFT(freq) + spat only as far as its own float32 transforms are
+            # accurate, and the decoder reconstructs from the edits, not from
+            # the device's eps.
+            eps_f = np.asarray(eps0, dtype=np.float64) + (
+                host_fft.irfftn(freq, s=plan.shape, axes=tuple(range(len(plan.shape))), workers=-1)
+                + spat
+            )
+            E_pol = (
+                plan.E_proj
+                if plan.E_grid_proj is None
+                else np.asarray(plan.E_grid_proj, dtype=np.float64)
+            )
+            eps_f, spat, freq, settled = polish_pocs_float64(
+                eps_f, spat, freq, E_pol, np.asarray(plan.Delta_proj, dtype=np.float64)
+            )
+            converged = bool(res.converged) and settled
+            final_violations = 0
+            if not converged:
+                # Surface non-convergence with an exact post-polish count: the
+                # float32 loop's exit count may overstate what the float64
+                # polish could not absorb.  Pair weights keep full-spectrum
+                # semantics, matching the loop's own violation accounting.
+                # (Converged runs skip the extra host rfftn — the default path
+                # pays nothing.)
+                d = np.fft.rfftn(eps_f)
+                tol = np.asarray(plan.Delta_proj, dtype=np.float64)
+                bad = (np.abs(d.real) > tol) | (np.abs(d.imag) > tol)
+                w = np.broadcast_to(np.asarray(rfft_pair_weights(plan.shape)), bad.shape)
+                final_violations = int(np.sum(w * bad))
         return FieldResult(
             eps=eps_f,
             spat=spat,
@@ -1211,12 +1226,14 @@ class CorrectionEngine:
         (see :func:`polish_pocs_float64`): False means the tensor is not
         converged, whatever the device loop reported.
         """
-        spat = np.asarray(spat_t, dtype=np.float64)
-        freq = np.asarray(freq_t, dtype=np.complex128)
-        eps_now = tiles0 + np.fft.irfft(freq, n=plan.block, axis=-1) + spat
-        _eps, spat, freq, settled = polish_pocs_float64(
-            eps_now, spat, freq, plan.E_proj, plan.Delta_proj, axes=(1,)
-        )
+        with span("ffcz.fetch"):
+            spat = np.asarray(spat_t, dtype=np.float64)
+            freq = np.asarray(freq_t, dtype=np.complex128)
+        with span("ffcz.polish"):
+            eps_now = tiles0 + np.fft.irfft(freq, n=plan.block, axis=-1) + spat
+            _eps, spat, freq, settled = polish_pocs_float64(
+                eps_now, spat, freq, plan.E_proj, plan.Delta_proj, axes=(1,)
+            )
         pair_w = np.asarray(rfft_pair_weights((plan.block,))).reshape(-1)
         k_s_max = int(np.count_nonzero(spat, axis=1).max()) if spat.size else 0
         wsum_max = float(((freq != 0) * pair_w).sum(axis=1).max()) if freq.size else 0.0

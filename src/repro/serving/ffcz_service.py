@@ -73,8 +73,13 @@ stage boundary for deterministic chaos testing (tests/test_faults.py); its
 per-request substreams make the injected faults identical in serial and
 pipelined mode.
 
+Each stage runs under a named profiler span (``ffcz.front``, ``ffcz.back``,
+``ffcz.wait`` and the stages inside them, :mod:`repro.core.spans`), and each
+request's stats carry how long it waited for FRONT (``queue_s``) and between
+FRONT and BACK (``handoff_s``).
+
 The prose version of this page — request kinds, error taxonomy, ladder,
-pipeline diagram, and the generated flag reference — is docs/serving.md
+pipeline diagram, spans, and the generated flag reference — is docs/serving.md
 (stream semantics: docs/streaming.md); keep them in sync.
 """
 
@@ -100,6 +105,7 @@ from repro.core.errors import (
     classify_exception,
 )
 from repro.core.ffcz import FFCz, FFCzBlob, FFCzConfig
+from repro.core.spans import span
 
 # The pencil envelope (FFSB) lives in repro.core.temporal (the temporal codec
 # shares it for pencil-mode stream frames); re-exported here because the
@@ -178,6 +184,10 @@ class RequestStats:
     # Derived-quantity shell recheck (cfg.verify_pspec, field requests in
     # pspec mode): max live-shell |P_hat(k)/P(k) - 1| of the decoded blob.
     pspec_shell_err: Optional[float] = None
+    # Waits on the service clock: admit -> start of the unit's FRONT, and end
+    # of FRONT -> start of BACK on the encode worker (0 at pipeline_depth 1).
+    queue_s: Optional[float] = None
+    handoff_s: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -209,6 +219,10 @@ class _Request:
     final_violations: int = 0
     iterations: Optional[int] = None
     pspec_shell_err: Optional[float] = None
+    # service-clock stamps: FRONT start and end, BACK start
+    t_front: Optional[float] = None
+    t_front_end: Optional[float] = None
+    t_back: Optional[float] = None
 
     def elapsed(self, now: float) -> float:
         return (now - self.t0) + self.penalty_s
@@ -281,9 +295,11 @@ class FFCzService:
             "buffer_evictions": 0,
         }
         # cumulative stage clocks (seconds): front = plan/base/pack/dispatch
-        # on the scheduler thread, execute = blocked on the device fence
-        # (incl. ladder re-runs), encode/decode = host codec work.  The serve
-        # bench turns these into host/device busy fractions.
+        # on the scheduler thread; execute = the device fence, the edit
+        # state's copy to the host and the float64 polish (incl. ladder
+        # re-runs; the spans ffcz.fence / ffcz.fetch / ffcz.polish split it);
+        # encode/decode = host codec work.  The serve bench turns these into
+        # host/device busy fractions.
         self.timers: Dict[str, float] = {
             "front_s": 0.0,
             "execute_s": 0.0,
@@ -564,7 +580,10 @@ class FFCzService:
         if self.config.pipeline_depth <= 1:
             if not self._queue:
                 return []
-            return self._back(self._front(self._pop_unit()))
+            unit = self._pop_unit()
+            staged = self._front(unit)
+            # inline BACK starts where FRONT ended: no handoff
+            return self._back(staged, start=unit[0].t_front_end)
         while self._queue and len(self._ring) < self.config.pipeline_depth:
             unit = self._pop_unit()
             staged = self._front(unit)
@@ -573,7 +592,8 @@ class FFCzService:
             return []
         unit, fut = self._ring.popleft()
         try:
-            return fut.result()
+            with span("ffcz.wait", uid=unit[0].uid):
+                return fut.result()
         except Exception as e:  # noqa: BLE001 - the back half never raises by
             # contract; anything here (e.g. a cancelled future at teardown)
             # still retires the unit with a structured rejection
@@ -628,10 +648,11 @@ class FFCzService:
         with self._lock:
             self.counters[name] += n
 
-    def _tick(self, name: str, t0: float) -> None:
-        dt = self._clock() - t0
+    def _tick(self, name: str, t0: float) -> float:
+        now = self._clock()
         with self._lock:
-            self.timers[name] += dt
+            self.timers[name] += now - t0
+        return now
 
     def _check_deadline(self, req: _Request) -> None:
         if req.elapsed(self._clock()) > req.deadline_s:
@@ -695,6 +716,8 @@ class FFCzService:
             final_violations=req.final_violations,
             iterations=req.iterations,
             pspec_shell_err=req.pspec_shell_err,
+            queue_s=None if req.t_front is None else req.t_front - req.t0,
+            handoff_s=None if req.t_back is None else req.t_back - req.t_front_end,
         )
 
     # -- staging-buffer cache ----------------------------------------------
@@ -730,67 +753,81 @@ class FFCzService:
     def _front(self, unit: List[_Request]) -> _Staged:
         """FRONT half, scheduler thread: plan/base + async EXECUTE dispatch."""
         t0 = self._clock()
+        for r in unit:
+            r.t_front = t0
+        kind = unit[0].kind
         try:
-            kind = unit[0].kind
-            if kind == "pencils":
-                return self._front_pencils(unit)
-            if kind == "field":
-                return self._front_field(unit[0])
-            # stream/session/decompress: nothing to pre-dispatch — the whole
-            # unit runs in the back half, overlapping OTHER units at
-            # depth >= 2.  Streams because the frame chain is sequential;
-            # sessions additionally because running every session op on the
-            # one ordered worker is what serializes a finalize racing queued
-            # appends (per-session FIFO).
-            return _Staged(kind=kind, unit=unit)
+            with span("ffcz.front", uid=unit[0].uid, kind=kind, n=len(unit)):
+                if kind == "pencils":
+                    return self._front_pencils(unit)
+                if kind == "field":
+                    return self._front_field(unit[0])
+                # stream/session/decompress: nothing to pre-dispatch — the
+                # whole unit runs in the back half, overlapping OTHER units at
+                # depth >= 2.  Streams because the frame chain is sequential;
+                # sessions additionally because running every session op on
+                # the one ordered worker is what serializes a finalize racing
+                # queued appends (per-session FIFO).
+                return _Staged(kind=kind, unit=unit)
         finally:
-            self._tick("front_s", t0)
+            t1 = self._tick("front_s", t0)
+            for r in unit:
+                r.t_front_end = t1
 
-    def _back(self, staged: _Staged) -> List[ServiceResponse]:
-        """BACK half, worker thread (or inline at depth 1): fence + retry
-        ladder + ENCODE.  Never raises — every request retires structured."""
-        if staged.kind == "pencils":
-            return self._back_pencils(staged)
-        if staged.kind == "field":
-            return [self._back_field(staged)]
-        if staged.kind == "stream":
+    def _back(self, staged: _Staged, start: Optional[float] = None) -> List[ServiceResponse]:
+        """BACK half, worker thread (or inline at depth 1, where ``start`` is
+        the end of FRONT): fence + retry ladder + ENCODE.  Never raises —
+        every request retires structured."""
+        start = self._clock() if start is None else start
+        for r in staged.unit:
+            r.t_back = start
+        lead = staged.unit[0]
+        with span("ffcz.back", uid=lead.uid, kind=staged.kind, n=len(staged.unit)):
+            if staged.kind == "pencils":
+                return self._back_pencils(staged)
+            if staged.kind == "field":
+                return [self._back_field(staged)]
+            if staged.kind == "stream":
+                t0 = self._clock()
+                try:
+                    return [self._run_stream(lead)]
+                finally:
+                    self._tick("execute_s", t0)
+            if staged.kind == "session":
+                t0 = self._clock()
+                try:
+                    return [self._run_session(lead)]
+                finally:
+                    self._tick("execute_s", t0)
             t0 = self._clock()
             try:
-                return [self._run_stream(staged.unit[0])]
+                return [self._run_decompress(lead)]
             finally:
-                self._tick("execute_s", t0)
-        if staged.kind == "session":
-            t0 = self._clock()
-            try:
-                return [self._run_session(staged.unit[0])]
-            finally:
-                self._tick("execute_s", t0)
-        t0 = self._clock()
-        try:
-            return [self._run_decompress(staged.unit[0])]
-        finally:
-            self._tick("decode_s", t0)
+                self._tick("decode_s", t0)
 
     # -- whole-field path --------------------------------------------------
 
     def _dispatch_field(self, req: _Request, eps0: np.ndarray, run_plan):
-        self._fire("dispatch", req.uid)
-        self._fire("oom", req.uid)
-        return self.engine.execute_field_async(eps0, run_plan)
+        with span("ffcz.dispatch"):
+            self._fire("dispatch", req.uid)
+            self._fire("oom", req.uid)
+            return self.engine.execute_field_async(eps0, run_plan)
 
     def _front_field(self, req: _Request) -> _Staged:
         try:
             cfg: FFCzConfig = req.cfg
             x32 = np.asarray(req.payload, dtype=np.float32)
-            plan = self._attempt(req, "plan", lambda: self.engine.plan_field(x32, cfg))
+            with span("ffcz.plan"):
+                plan = self._attempt(req, "plan", lambda: self.engine.plan_field(x32, cfg))
 
             def _base():
                 self._fire("codec", req.uid)
                 blob = self.base.compress(x32, plan.E_proj)
                 return blob, np.asarray(self.base.decompress(blob), dtype=np.float32)
 
-            base_blob, x_hat = self._attempt(req, "base", _base)
-            eps0 = x_hat - x32
+            with span("ffcz.base"):
+                base_blob, x_hat = self._attempt(req, "base", _base)
+                eps0 = x_hat - x32
             # attempt 1 of the first ladder rung dispatches here so the device
             # starts while the previous unit is still encoding; failures are
             # stashed raw and re-raised inside the back half's ladder, which
@@ -836,20 +873,21 @@ class FFCzService:
 
             t0 = self._clock()
             try:
-                se, fe = self._attempt(req, "encode", _encode)
                 cfg: FFCzConfig = req.cfg
-                blob = FFCzBlob(
-                    base_blob=staged.base_blob,
-                    spat_edits=se,
-                    freq_edits=fe,
-                    E=run_plan.E,
-                    Delta_scalar=run_plan.delta_scalar,
-                    pointwise_delta=run_plan.pointwise_bytes(),
-                    shape=run_plan.shape,
-                    roi_bound=run_plan.roi_bytes(),
-                    crc=cfg.crc,
-                )
-                payload = blob.to_bytes()
+                with span("ffcz.encode"):
+                    se, fe = self._attempt(req, "encode", _encode)
+                    blob = FFCzBlob(
+                        base_blob=staged.base_blob,
+                        spat_edits=se,
+                        freq_edits=fe,
+                        E=run_plan.E,
+                        Delta_scalar=run_plan.delta_scalar,
+                        pointwise_delta=run_plan.pointwise_bytes(),
+                        shape=run_plan.shape,
+                        roi_bound=run_plan.roi_bytes(),
+                        crc=cfg.crc,
+                    )
+                    payload = blob.to_bytes()
                 if getattr(cfg, "verify_pspec", False) and cfg.pspec_rel is not None:
                     # derived-quantity recheck rides the encode stage: decode
                     # the assembled blob and measure the live-shell power-
@@ -936,18 +974,19 @@ class FFCzService:
         """One fused dispatch per bucket attempt -> one dispatch/OOM draw,
         always against the ORIGINAL bucket lead's uid (``fire_uid``), so
         injected-fault caps span the whole bisect recursion."""
-        self._fire("dispatch", fire_uid)
-        self._fire("oom", fire_uid)
-        return self.engine.correct_async(
-            [w[2] for w in work],
-            [w[4].E_proj for w in work],
-            [w[4].Delta_proj for w in work],
-            block=self.config.block,
-            max_iters=self.config.max_iters,
-            return_edits=True,
-            return_corrected=False,
-            staging=staging,
-        )
+        with span("ffcz.dispatch"):
+            self._fire("dispatch", fire_uid)
+            self._fire("oom", fire_uid)
+            return self.engine.correct_async(
+                [w[2] for w in work],
+                [w[4].E_proj for w in work],
+                [w[4].Delta_proj for w in work],
+                block=self.config.block,
+                max_iters=self.config.max_iters,
+                return_edits=True,
+                return_corrected=False,
+                staging=staging,
+            )
 
     def _front_pencils(self, bucket: List[_Request]) -> _Staged:
         """Per-request plan/base, then ONE fused async dispatch."""
@@ -957,13 +996,14 @@ class FFCzService:
             try:
                 E_rel, Delta_rel = req.cfg
                 x32 = np.asarray(req.payload, dtype=np.float32)
-                plan = self._attempt(
-                    req,
-                    "plan",
-                    lambda x=x32, e=E_rel, d=Delta_rel: self.engine.plan_pencils(
-                        x, E_rel=e, Delta_rel=d, block=self.config.block
-                    ),
-                )
+                with span("ffcz.plan"):
+                    plan = self._attempt(
+                        req,
+                        "plan",
+                        lambda x=x32, e=E_rel, d=Delta_rel: self.engine.plan_pencils(
+                            x, E_rel=e, Delta_rel=d, block=self.config.block
+                        ),
+                    )
                 if plan is None:
                     raise InfeasibleBound(
                         f"E_rel={E_rel:g} underflows float32 for this tensor's range",
@@ -975,8 +1015,9 @@ class FFCzService:
                     blob = self.base.compress(x, p.E_proj)
                     return blob, np.asarray(self.base.decompress(blob), dtype=np.float32)
 
-                base_blob, x_hat = self._attempt(req, "base", _base)
-                eps0 = x_hat - x32
+                with span("ffcz.base"):
+                    base_blob, x_hat = self._attempt(req, "base", _base)
+                    eps0 = x_hat - x32
                 tiles0 = self.engine.tile_f64(eps0, self.config.block)
                 work.append((req, base_blob, eps0, tiles0, plan))
             except FFCzError as err:
@@ -1063,26 +1104,27 @@ class FFCzService:
         out = []
         t0 = self._clock()
         try:
-            for j, ((req, base_blob, _eps0, tiles0, plan), (spat_t, freq_t)) in enumerate(
-                zip(work, edits)
-            ):
-                req.converged = bool(conv[j]) if conv.size else True
-                req.iterations = int(iters[j]) if iters.size else 0
-                try:
+            with span("ffcz.encode"):
+                for j, ((req, base_blob, _eps0, tiles0, plan), (spat_t, freq_t)) in enumerate(
+                    zip(work, edits)
+                ):
+                    req.converged = bool(conv[j]) if conv.size else True
+                    req.iterations = int(iters[j]) if iters.size else 0
+                    try:
 
-                    def _encode(s=spat_t, f=freq_t, t=tiles0, p=plan, r=req):
-                        self._fire("codec", r.uid)
-                        return self.engine.encode_pencils(s, f, t, p, codec="zlib")
+                        def _encode(s=spat_t, f=freq_t, t=tiles0, p=plan, r=req):
+                            self._fire("codec", r.uid)
+                            return self.engine.encode_pencils(s, f, t, p, codec="zlib")
 
-                    se, fe, settled = self._attempt(req, "encode", _encode)
-                    req.converged = req.converged and settled
-                    x = np.asarray(req.payload)
-                    payload = _pencil_blob(x.shape, base_blob, se, fe, plan, self.config.block)
-                    out.append(self._complete(req, payload))
-                except FFCzError as err:
-                    out.append(self._reject(req, err))
-                except Exception as e:  # noqa: BLE001
-                    out.append(self._reject(req, classify_exception(e, "encode")))
+                        se, fe, settled = self._attempt(req, "encode", _encode)
+                        req.converged = req.converged and settled
+                        x = np.asarray(req.payload)
+                        payload = _pencil_blob(x.shape, base_blob, se, fe, plan, self.config.block)
+                        out.append(self._complete(req, payload))
+                    except FFCzError as err:
+                        out.append(self._reject(req, err))
+                    except Exception as e:  # noqa: BLE001
+                        out.append(self._reject(req, classify_exception(e, "encode")))
         finally:
             self._tick("encode_s", t0)
         return out
