@@ -42,6 +42,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
@@ -81,6 +84,150 @@ _BACKENDS = ("local", "batched", "sharded")
 #: near 1e-15 of Delta on 128^3 Nyx-like fields).
 POLISH_FLOOR_REL = 1e-12
 
+#: Elements below which a polish pass runs inline on the calling thread
+#: (pencil buckets, checkpoint, KV and gradient batches); at or above it the
+#: pass is split into ``_SLABS`` slabs along axis 0 that run on ``_POOL``
+#: (whole fields).
+_THREADED_MIN = 1 << 20
+
+
+def _cores() -> int:
+    """The cores this process may run on (``os.cpu_count()`` where the
+    platform keeps no affinity mask)."""
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0))
+    return os.cpu_count() or 1
+
+
+# two slabs a core: enough to even out the cores, few enough that a slab's
+# numpy calls outweigh its dispatch
+_SLABS = 2 * _cores()
+
+
+def _new_pool():
+    global _POOL
+    _POOL = ThreadPoolExecutor(max_workers=_cores(), thread_name_prefix="ffcz-polish")
+
+
+_new_pool()
+# a forked child inherits the pool object but not its threads
+os.register_at_fork(after_in_child=_new_pool)
+
+
+def _slab_count(shape) -> int:
+    """Slabs a polish pass over an input of ``shape`` is split into: one,
+    run inline, below ``_THREADED_MIN`` elements."""
+    if math.prod(shape) < _THREADED_MIN:
+        return 1
+    return min(shape[0], _SLABS)
+
+
+def _slab_ranges(shape, k: int) -> list:
+    """Flat ``[a, b)`` offsets of ``k`` (at most) even slabs along axis 0
+    of a C-order array of ``shape``."""
+    size = math.prod(shape)
+    n0 = shape[0] if shape else 1
+    k = max(1, min(k, n0))
+    if k == 1:
+        return [(0, size)]
+    row = size // n0
+    edges = [j * n0 // k * row for j in range(k + 1)]
+    return list(zip(edges[:-1], edges[1:]))
+
+
+def _each_slab(fn, ranges) -> list:
+    """``fn(a, b)`` over ``ranges``, inline for one slab, else on ``_POOL``.
+
+    numpy releases the GIL inside elementwise loops, so the slabs run on
+    every core; each slab sees the same operations whichever thread runs it.
+    """
+    if len(ranges) == 1:
+        return [fn(*ranges[0])]
+    return list(_POOL.map(lambda r: fn(*r), ranges))
+
+
+def _cube(b, shape):
+    """``(-b, b)``: floats for a scalar bound, flat C-order float64 arrays
+    over ``shape`` for a bound grid."""
+    b = np.asarray(b, dtype=np.float64)
+    if b.ndim == 0:
+        return -float(b), float(b)
+    hi = np.ascontiguousarray(np.broadcast_to(b, shape)).reshape(-1)
+    return -hi, hi
+
+
+def _copy_f(x, dtype, k: int):
+    """A C-order ``dtype`` copy of ``x``, in ``k`` slabs."""
+    out = np.empty(np.shape(x), dtype=dtype)
+    src = np.ascontiguousarray(x).reshape(-1)
+    dst = out.reshape(-1)
+
+    def one(a, b):
+        dst[a:b] = src[a:b]
+
+    _each_slab(one, _slab_ranges(out.shape, k))
+    return out
+
+
+def _clip_spectrum(d, cube, k: int):
+    """Clip the C-order spectrum ``d`` in place to the f-cube ``(lo, hi)``,
+    in ``k`` slabs.
+
+    Returns ``(hits, excess)``: per slab with a clipped component, the flat
+    indices of those components and their displacement ``clip(d) - d``; and
+    the largest ``|displacement|`` (0.0 when nothing is clipped).  Only the
+    clipped components are written: every other one would move by ``+ 0``.
+    """
+    lo, hi = cube
+    flat = d.reshape(-1)
+    grid = not isinstance(hi, float)
+
+    def one(a, b):
+        v = flat[a:b]
+        w = v.view(np.float64).reshape(-1, 2)  # (re, im) per component
+        lo_v, hi_v = (lo[a:b, None], hi[a:b, None]) if grid else (lo, hi)
+        out = w > hi_v
+        out |= w < lo_v
+        i = np.unique(np.flatnonzero(out) >> 1)
+        if not i.size:
+            return i, None, 0.0
+        x = v[i]
+        lo_i, hi_i = (lo[a:b][i], hi[a:b][i]) if grid else (lo, hi)
+        re = np.clip(x.real, lo_i, hi_i)
+        im = np.clip(x.imag, lo_i, hi_i)
+        disp = (re - x.real) + 1j * (im - x.imag)
+        v[i] = re + 1j * im
+        return i + a, disp, float(np.max(np.abs(disp)))
+
+    out = _each_slab(one, _slab_ranges(d.shape, k))
+    hits = [(i, disp) for i, disp, _m in out if i.size]
+    return hits, max(m for _i, _d, m in out)
+
+
+def _clip_spatial(eps, spat, cube, k: int) -> int:
+    """Clip the C-order ``eps`` in place to the s-cube ``(lo, hi)``, in
+    ``k`` slabs, adding each clipped point's displacement to ``spat`` (in
+    place); returns the number of points clipped."""
+    lo, hi = cube
+    ef, sf = eps.reshape(-1), spat.reshape(-1)
+    grid = not isinstance(hi, float)
+
+    def one(a, b):
+        x = ef[a:b]
+        lo_v, hi_v = (lo[a:b], hi[a:b]) if grid else (lo, hi)
+        out = x > hi_v
+        out |= x < lo_v
+        i = np.flatnonzero(out)
+        if i.size:
+            xi = x[i]
+            c = np.clip(xi, lo_v[i], hi_v[i]) if grid else np.clip(xi, lo, hi)
+            s = sf[a:b]
+            s[i] = s[i] + (c - xi)
+            x[i] = c
+        return i.size
+
+    return sum(_each_slab(one, _slab_ranges(eps.shape, k)))
+
 
 def polish_pocs_float64(eps, spat, freq, E, Delta, axes=None, max_iters: int = 30):
     """Exact (float64) POCS iterations to absorb float32 FFT round-off.
@@ -101,35 +248,66 @@ def polish_pocs_float64(eps, spat, freq, E, Delta, axes=None, max_iters: int = 3
     holds exactly either way.
 
     Returns ``(eps, spat, freq, settled)``; ``settled`` is False when the
-    ``max_iters`` cap left a frequency excess above that floor.
+    ``max_iters`` cap left a frequency excess above that floor.  The
+    arguments are not modified.
 
     The transforms are scipy's pocketfft on every host core (``workers=-1``):
     each line is transformed alone, so the result does not depend on the
-    thread count, and a 512^3 field's polish no longer waits on one core.
-    Each round trip past the check (one ``irfftn`` and its clips) is one
-    ``ffcz.polish.round`` span.
+    thread count.  The clips between them write only the components and
+    points that lie outside their cube (a few hundred of a field's spectrum,
+    about 1% of its points), in place, slab by slab along axis 0: inline
+    below ``_THREADED_MIN`` elements, else on every core.  An untouched
+    value keeps its bits where a dense pass would add ``0.0`` to it, so the
+    result equals the dense clip-and-add loop's bit for bit (up to the sign
+    of a zero).  Each round trip past the check (the ``freq`` update, one
+    ``irfftn`` and its clips) is one ``ffcz.polish.round`` span carrying
+    ``f_clipped`` (components clipped) and ``s_clipped`` (points clipped).
     """
-    axes = tuple(range(eps.ndim)) if axes is None else tuple(axes)
+    axes = tuple(range(np.ndim(eps))) if axes is None else tuple(axes)
+    eps = np.asarray(eps, dtype=np.float64)
     s = [eps.shape[a] for a in axes]
+    half = list(eps.shape)
+    half[axes[-1]] = half[axes[-1]] // 2 + 1
     floor = POLISH_FLOOR_REL * float(np.max(Delta)) if np.size(Delta) else 0.0
+    k = _slab_count(eps.shape)
+    s_cube = _cube(E, eps.shape)
+    f_cube = _cube(Delta, tuple(half))
+    owned = False
     prev = np.inf
     for it in range(max_iters + 1):
-        delta = host_fft.rfftn(eps, axes=axes, workers=-1)
-        re = np.clip(delta.real, -Delta, Delta)
-        im = np.clip(delta.imag, -Delta, Delta)
-        disp = (re - delta.real) + 1j * (im - delta.imag)
-        excess = float(np.max(np.abs(disp))) if disp.size else 0.0
+        d = np.ascontiguousarray(host_fft.rfftn(eps, axes=axes, workers=-1))
+        hits, excess = _clip_spectrum(d, f_cube, k)
         if excess == 0.0 or prev <= excess <= floor or it == max_iters:
             break
         prev = excess
-        with span("ffcz.polish.round"):
-            freq = freq + disp
-            clipped = re + 1j * im
-            eps_f = host_fft.irfftn(clipped, s=s, axes=axes, workers=-1)
-            eps_s = np.clip(eps_f, -E, E)
-            spat = spat + (eps_s - eps_f)
-            eps = eps_s
+        with span("ffcz.polish.round", f_clipped=sum(i.size for i, _disp in hits)) as sp:
+            if not owned:
+                spat, freq = _copy_f(spat, np.float64, k), _copy_f(freq, np.complex128, k)
+                owned = True
+            ff = freq.reshape(-1)
+            for i, disp in hits:
+                ff[i] += disp
+            eps_f = np.ascontiguousarray(host_fft.irfftn(d, s=s, axes=axes, workers=-1))
+            sp.set_metadata(s_clipped=_clip_spatial(eps_f, spat, s_cube, k))
+            eps = eps_f
     return eps, spat, freq, excess <= floor
+
+
+def _rebuild_f64(eps0, eps_freq, spat):
+    """``eps0 + (eps_freq + spat)`` in float64, the polish's start state,
+    summed slab by slab into ``eps_freq`` (a float64 array it may write)."""
+    eps_freq = np.ascontiguousarray(eps_freq)
+    out = eps_freq.reshape(-1)
+    e0 = np.ascontiguousarray(eps0).reshape(-1)
+    sf = np.ascontiguousarray(spat).reshape(-1)
+
+    def one(a, b):
+        seg = out[a:b]
+        seg += sf[a:b]
+        seg += e0[a:b]  # widened exactly; a + b == b + a in IEEE
+
+    _each_slab(one, _slab_ranges(eps_freq.shape, _slab_count(eps_freq.shape)))
+    return eps_freq
 
 
 def _host_l2_norm(x32: np.ndarray) -> float:
@@ -886,15 +1064,16 @@ class CorrectionEngine:
                 spat = eps0.unpad_spatial(spat)
                 freq = eps0.unpad_freq(freq)
                 eps0 = eps0.to_host()
-        with span("ffcz.polish"):
+        with span("ffcz.polish", slabs=_slab_count(plan.shape)):
             # The polish starts from the state the edit streams encode,
             # rebuilt in float64: the device loop keeps eps == eps0 +
             # IFFT(freq) + spat only as far as its own float32 transforms are
             # accurate, and the decoder reconstructs from the edits, not from
             # the device's eps.
-            eps_f = np.asarray(eps0, dtype=np.float64) + (
-                host_fft.irfftn(freq, s=plan.shape, axes=tuple(range(len(plan.shape))), workers=-1)
-                + spat
+            eps_f = _rebuild_f64(
+                eps0,
+                host_fft.irfftn(freq, s=plan.shape, axes=tuple(range(len(plan.shape))), workers=-1),
+                spat,
             )
             E_pol = (
                 plan.E_proj
@@ -1229,7 +1408,7 @@ class CorrectionEngine:
         with span("ffcz.fetch"):
             spat = np.asarray(spat_t, dtype=np.float64)
             freq = np.asarray(freq_t, dtype=np.complex128)
-        with span("ffcz.polish"):
+        with span("ffcz.polish", slabs=_slab_count(np.shape(tiles0))):
             eps_now = tiles0 + np.fft.irfft(freq, n=plan.block, axis=-1) + spat
             _eps, spat, freq, settled = polish_pocs_float64(
                 eps_now, spat, freq, plan.E_proj, plan.Delta_proj, axes=(1,)

@@ -6,7 +6,8 @@ with ``jax.profiler.ProfileData``.  Every stage span appears once per unit
 (PLAN and the base codec once per request), the stages nest in their
 unit's ``ffcz.front`` / ``ffcz.back`` on the scheduler and the encode worker
 respectively, the ``uid`` stats join a unit's spans across the two threads,
-and each ``ffcz.polish.round`` is one float64 ``irfftn`` of the polish.
+and each ``ffcz.polish.round`` is one float64 ``irfftn`` of the polish and
+carries its clip counts.
 
 ``queue_s`` / ``handoff_s`` are checked exactly on a clock that only the
 stages advance: the base codec costs 1 s of FRONT, the encoder 10 s of BACK.
@@ -158,6 +159,26 @@ def test_polish_rounds_are_the_polish_irfftn_calls(traced):
     n = sum(sp[0] == "ffcz.polish.round" for sp in traced["spans"])
     assert traced["rounds"] > 0
     assert n == traced["rounds"]
+
+
+def test_polish_spans_carry_slabs_and_clip_counts(traced):
+    """``ffcz.polish`` says how many slabs its passes ran in (1: a 16^3
+    field and a pencil bucket run inline); each ``ffcz.polish.round`` says
+    how many components and points it clipped, and a round runs only when
+    some component lies outside the f-cube."""
+    spans = traced["spans"]
+    polishes = [sp for sp in spans if sp[0] == "ffcz.polish"]
+    assert len(polishes) == 3 and all(sp[4] == {"slabs": 1} for sp in polishes)
+    back, _inner = _unit(spans, "ffcz.back", traced["field"])
+    (polish,) = [sp for sp in _inside(spans, back) if sp[0] == "ffcz.polish"]
+    rounds = sorted(
+        (sp for sp in _inside(spans, polish) if sp[0] == "ffcz.polish.round"), key=lambda sp: sp[2]
+    )
+    assert rounds and rounds[0][4]["f_clipped"] > 0
+    for sp in spans:
+        if sp[0] == "ffcz.polish.round":
+            assert set(sp[4]) == {"f_clipped", "s_clipped"}
+            assert sp[4]["f_clipped"] > 0 and sp[4]["s_clipped"] >= 0
 
 
 # -- wait counters on a clock only the stages advance ----------------------
