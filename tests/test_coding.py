@@ -1,5 +1,9 @@
 """Unit + property tests for the entropy-coding layer."""
 
+import struct
+import zlib
+from concurrent.futures import ThreadPoolExecutor
+
 import numpy as np
 import pytest
 from _hyp import given, settings, st  # hypothesis or skip-stubs (requirements-dev.txt)
@@ -7,6 +11,7 @@ from _hyp import given, settings, st  # hypothesis or skip-stubs (requirements-d
 from repro.coding import (
     huffman_decode,
     huffman_encode,
+    lossless,
     lossless_compress,
     lossless_decompress,
     pack_bits,
@@ -166,6 +171,84 @@ class TestLossless:
     def test_bad_codec(self):
         with pytest.raises(ValueError):
             lossless_compress(np.zeros(3), codec="nope")
+
+
+CHUNK = lossless.DEFLATE_CHUNK_BYTES
+# streams the serial coder wrote: arange(-3, 4) as "zlib", [5, 5, 5, -7, 300]
+# as "huffman+zlib"
+OLD_ZLIB = bytes.fromhex("4652789c4b62678080bffffe333032310300187d036a")
+OLD_HUFF = bytes.fromhex("4648789c63666060f8f91f0258192040871142333132c184d8a1b41800492b084f")
+
+
+def _int8_symbols(body_len, rng, period=20_000):
+    """Symbols whose ``codec="zlib"`` body (9-byte header, then int8) is
+    ``body_len`` bytes: random bytes repeating every ``period``, so past the
+    first period every match reaches back across chunk boundaries."""
+    block = rng.integers(-128, 128, period)
+    return np.resize(block, body_len - 9)
+
+
+def _body(s):
+    return struct.pack("<cQ", b"b", s.size) + s.astype(np.int8).tobytes()
+
+
+class TestChunkedDeflate:
+    """Bodies longer than one chunk are deflated in chunks on a pool and
+    joined into one zlib stream; shorter ones keep ``zlib.compress``."""
+
+    @pytest.mark.parametrize(
+        "body_len, chunk",
+        [
+            (CHUNK, CHUNK),  # exactly one chunk: serial
+            (CHUNK + 1, CHUNK),  # one byte over: a 1-byte last chunk
+            (3 * CHUNK + 4321, CHUNK),  # several chunks
+            (50_000, 1000),  # chunks far shorter than the 32 KiB dictionary
+        ],
+        ids=["one_chunk", "one_byte_over", "several", "tiny_chunks"],
+    )
+    def test_roundtrip_one_zlib_stream(self, body_len, chunk, rng, monkeypatch):
+        monkeypatch.setattr(lossless, "DEFLATE_CHUNK_BYTES", chunk)
+        s = _int8_symbols(body_len, rng)
+        body = _body(s)
+        assert len(body) == body_len
+        out = lossless_compress(s, codec="zlib")
+        serial = b"FR" + zlib.compress(body, 6)
+        assert np.array_equal(lossless_decompress(out), s)
+        assert zlib.decompress(out[2:]) == body
+        assert (out == serial) == (body_len <= chunk)
+        # each chunk is primed with the window before it, so the matches
+        # that span a boundary cost what they cost in the serial stream; a
+        # chunk adds its flush marker and block headers
+        assert len(out) <= len(serial) * 1.002 + 16 * -(-body_len // chunk)
+
+    @pytest.mark.parametrize("chunk", [CHUNK, 4096])
+    def test_bytes_do_not_depend_on_the_pool(self, chunk, rng, monkeypatch):
+        monkeypatch.setattr(lossless, "DEFLATE_CHUNK_BYTES", chunk)
+        s = rng.integers(-300, 300, 3 * CHUNK // 2)  # int16, ~3 MiB of body
+        outs = []
+        for workers in (1, 4):
+            with ThreadPoolExecutor(workers) as pool:
+                monkeypatch.setattr(lossless, "_POOL", pool)
+                outs.append(lossless_compress(s, codec="zlib"))
+        assert outs[0] == outs[1]
+        assert np.array_equal(lossless_decompress(outs[0]), s)
+
+    @pytest.mark.parametrize("n", [0, 1, 3000, CHUNK // 2 - 9])
+    def test_below_two_chunks_is_the_serial_stream(self, n, rng):
+        s = rng.integers(-1000, 1000, n)
+        s[:1] = 999  # an int16 body; an empty stream is an int8 one
+        body = struct.pack("<cQ", b"h" if n else b"b", n) + s.astype(np.int16).tobytes()
+        assert lossless_compress(s, codec="zlib") == b"FR" + zlib.compress(body, 6)
+        assert lossless_compress(s) == b"FH" + zlib.compress(huffman_encode(s), 6)
+
+    def test_streams_written_before_still_decode(self, rng):
+        assert np.array_equal(lossless_decompress(OLD_ZLIB), np.arange(-3, 4))
+        assert np.array_equal(lossless_decompress(OLD_HUFF), [5, 5, 5, -7, 300])
+        assert lossless_compress(np.arange(-3, 4), codec="zlib") == OLD_ZLIB
+        assert lossless_compress(np.array([5, 5, 5, -7, 300])) == OLD_HUFF
+        # a multi-chunk body as the serial coder deflated it
+        s = _int8_symbols(2 * CHUNK + 99, rng)
+        assert np.array_equal(lossless_decompress(b"FR" + zlib.compress(_body(s), 6)), s)
 
 
 class TestQuantize:

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st  # hypothesis or skip-stubs (requirements-dev.txt)
 
+from repro.coding import lossless
 from repro.compressors import get_compressor
 
 NAMES = ["szlike", "zfplike", "sperrlike", "identity"]
@@ -64,3 +65,21 @@ class TestRatioOrdering:
         z = get_compressor("zfplike").compress(x, 1e-3 * np.ptp(x))
         i = get_compressor("identity").compress(x, 1e-3)
         assert len(z) < len(i)
+
+
+class TestChunkedBaseCodec:
+    """A 128^3 nyx-like field's int16 code stream (~4 MiB) spans several
+    deflate chunks, so szlike's payload is deflated on the pool."""
+
+    def test_szlike_decodes_as_the_serial_blob(self, monkeypatch):
+        from repro.data.fields import make_field
+
+        x = make_field("nyx-like-128")
+        E = 1e-3 * float(np.ptp(x))
+        c = get_compressor("szlike")
+        blob = c.compress(x, E)
+        monkeypatch.setattr(lossless, "DEFLATE_CHUNK_BYTES", 1 << 40)  # one chunk: serial
+        serial = c.compress(x, E)
+        assert blob != serial
+        assert np.array_equal(c.decompress(blob), c.decompress(serial))
+        assert abs(len(blob) - len(serial)) <= 0.002 * len(serial)

@@ -181,6 +181,29 @@ def test_polish_spans_carry_slabs_and_clip_counts(traced):
             assert sp[4]["f_clipped"] > 0 and sp[4]["s_clipped"] >= 0
 
 
+def test_deflate_spans_count_their_chunks(traced, tmp_path):
+    """Each pencil's base codec deflates its small code stream in one chunk
+    (``zlib.compress``); a 128^3 field's ~4 MiB stream is deflated in
+    chunks, and ``ffcz.deflate`` says how many and over how many bytes."""
+    from repro.coding import lossless
+    from repro.data.fields import make_field
+
+    spans, lead = traced["spans"], traced["pencils"][0]
+    front, _inner = _unit(spans, "ffcz.front", lead)
+    bases = [sp for sp in _inside(spans, front) if sp[0] == "ffcz.base"]
+    assert len(bases) == 2
+    for base in bases:
+        (deflate,) = [sp for sp in _inside(spans, base) if sp[0] == "ffcz.deflate"]
+        assert deflate[4]["chunks"] == 1 and 0 < deflate[4]["bytes"] <= lossless.DEFLATE_CHUNK_BYTES
+
+    x = make_field("nyx-like-128")
+    with jax.profiler.trace(str(tmp_path)):
+        get_compressor("szlike").compress(x, 1e-3 * float(np.ptp(x)))
+    (deflate,) = [sp for sp in _read_spans(tmp_path) if sp[0] == "ffcz.deflate"]
+    stats = deflate[4]
+    assert stats["chunks"] == -(-stats["bytes"] // lossless.DEFLATE_CHUNK_BYTES) > 1
+
+
 # -- wait counters on a clock only the stages advance ----------------------
 
 
