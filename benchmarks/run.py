@@ -10,7 +10,6 @@ CSV; JSON artifacts land in benchmarks/results/.
   table3_iters      Table III  iterations / active edits vs Delta
   table4_kernels    Table IV   kernel-level breakdown
   fig10_pspec       Fig. 10    power-spectrum ribbon
-  roofline          —          dry-run roofline terms (EXPERIMENTS.md §Roofline)
 
 ``python -m benchmarks.run [--quick] [--only name]``
 """
@@ -32,7 +31,6 @@ def main() -> None:
         fig7_throughput,
         fig8_psnr,
         fig10_pspec,
-        roofline,
         table2_ratio,
         table3_iters,
         table4_kernels,
@@ -47,7 +45,6 @@ def main() -> None:
         "table3_iters": table3_iters,
         "table4_kernels": table4_kernels,
         "fig10_pspec": fig10_pspec,
-        "roofline": roofline,
     }
     if args.only:
         modules = {k: v for k, v in modules.items() if k in args.only.split(",")}

@@ -46,8 +46,7 @@ from scipy import fft as host_fft  # noqa: E402
 from repro.compressors import get_compressor  # noqa: E402
 from repro.configs.ffcz_fields import FIELDS  # noqa: E402
 from repro.core.engine import CorrectionEngine  # noqa: E402
-from repro.core.ffcz import FFCz, FFCzBlob, FFCzConfig  # noqa: E402
-from repro.core.temporal import _PENCIL_HEADER, _PENCIL_MAGIC  # noqa: E402
+from repro.core.ffcz import PENCIL_HEADER, PENCIL_MAGIC, FFCz, FFCzBlob, FFCzConfig  # noqa: E402
 from repro.data.fields import make_field  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.serve_ffcz import add_fault_args, add_service_args, build_service  # noqa: E402
@@ -101,7 +100,7 @@ def nyx_field(edge: int, seed: int) -> np.ndarray:
 
 def eeg_batch(n: int, channels: int, samples: int, seed: int):
     """EEG-like channels x time recordings (random-walk drift + a shared
-    oscillation + sensor noise), as in the ``stream/eeg-pencils`` bench row."""
+    oscillation + sensor noise)."""
     rng = np.random.default_rng(seed)
     t = np.linspace(0, 6 * np.pi, samples)[None, :]
     return [
@@ -133,8 +132,8 @@ def check_bounds(label: str, x: np.ndarray, x_hat: np.ndarray, E: float, Delta: 
 
 def pencil_bounds(blob: bytes):
     """(E, Delta, block) from a service pencil blob's header."""
-    assert blob[:4] == _PENCIL_MAGIC, "not a pencil blob"
-    E, Delta, block, _ndim = struct.unpack_from(_PENCIL_HEADER, blob, 5)
+    assert blob[:4] == PENCIL_MAGIC, "not a pencil blob"
+    E, Delta, block, _ndim = struct.unpack_from(PENCIL_HEADER, blob, 5)
     return E, Delta, block
 
 

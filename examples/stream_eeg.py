@@ -19,8 +19,8 @@ whole-field rfftn.  The demo:
    seek-decode is bitwise identical to the sequential decode,
 5. prints per-frame POCS iteration counts (residual frames warm-start from
    the previous frame's edit spectrum; the controlled warm-vs-cold
-   iteration measurement is the ``stream/warm-vs-cold`` row recorded by
-   ``benchmarks/bench_pocs.py``).
+   iteration check is ``tests/test_temporal.py``'s
+   ``test_warm_chain_cuts_pocs_iterations``).
 """
 
 import argparse

@@ -35,7 +35,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-from repro.compressors import get_compressor
+from repro.compressors import base_residual, get_compressor
 from repro.core.edits import EncodedEdits, decode_edits
 from repro.core.engine import CorrectionEngine, default_engine
 from repro.core.ffcz import FFCz, FFCzBlob, FFCzConfig
@@ -133,9 +133,7 @@ class CheckpointCodec:
                 # range below float32 representability — store raw instead
                 out[i] = self._raw(arrays[i])
                 continue
-            base_blob = self.base.compress(x32, plan.E_proj)
-            x_hat = np.asarray(self.base.decompress(base_blob), dtype=np.float32)
-            eps0 = x_hat - x32
+            base_blob, eps0 = base_residual(self.base, x32, plan.E_proj)
             # float64 tiling captured up front: the polish rebuilds the loop
             # state from it, so eps0 itself need not outlive the batched call
             tiles0 = self.engine.tile_f64(eps0, block)

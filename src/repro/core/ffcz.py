@@ -9,7 +9,8 @@ the three engine stages of :class:`repro.core.engine.CorrectionEngine`:
                 grids built from a device rfft (and only when a bound
                 actually consumes the spectrum — Delta_abs skips the
                 forward FFT entirely)
-    2.          base.compress(x, E_proj)    -> base blob (spatially bounded)
+    2.          base_residual(base, x, E_proj)
+                -> base blob (spatially bounded) + its error x_hat - x
     3. EXECUTE  engine.execute_field(x_hat - x, plan)
                 -> one jitted device POCS program (Hermitian rfft
                 half-spectrum loop) + exact float64 polish
@@ -32,17 +33,24 @@ length-validated section table; version-0 (magic-less) blobs from older
 writers are sniffed and still decode, including legacy full-spectrum
 frequency streams (``EncodedEdits.half_spectrum`` clear) via the ``ifftn``
 branch of :meth:`FFCz.decompress`.
+
+The pencil envelope ``FFSB`` (:func:`encode_pencil_blob` /
+:func:`decode_pencil_blob`) is the other FFCz wire format: one pencil-planned
+tensor, as the service's pencil responses and the temporal codec's
+pencil-mode frames carry it.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import struct
+import zlib
 from typing import Any, Optional
 
 import numpy as np
 
 from repro.coding.quantize import DEFAULT_QUANT_BITS
+from repro.compressors import base_residual
 from repro.core.cubes import rfft_shape
 from repro.core.edits import EncodedEdits, decode_edits
 from repro.core.errors import BlobCorruptError, FFCzError
@@ -64,6 +72,8 @@ __all__ = [
     "PadMeta",
     "ShardedField",
     "adaptive_quant_bits",
+    "decode_pencil_blob",
+    "encode_pencil_blob",
     "float32_bound_discipline",
     "polish_pocs_float64",
 ]
@@ -290,6 +300,30 @@ class FFCzBlob:
     # writer) stay byte-identical.
     crc: bool = False
 
+    @staticmethod
+    def from_plan(
+        plan,
+        base_blob: bytes,
+        spat_edits: EncodedEdits,
+        freq_edits: EncodedEdits,
+        crc: bool,
+        pad_meta: Optional[PadMeta] = None,
+    ) -> "FFCzBlob":
+        """The blob every writer assembles from its
+        :class:`~repro.core.engine.FieldPlan`, base blob and edit streams."""
+        return FFCzBlob(
+            base_blob=base_blob,
+            spat_edits=spat_edits,
+            freq_edits=freq_edits,
+            E=plan.E,
+            Delta_scalar=plan.delta_scalar,
+            pointwise_delta=plan.pointwise_bytes(),
+            shape=plan.shape,
+            pad_meta=pad_meta,
+            roi_bound=plan.roi_bytes(),
+            crc=crc,
+        )
+
     def to_bytes(self) -> bytes:
         se = self.spat_edits.to_bytes()
         fe = self.freq_edits.to_bytes()
@@ -311,8 +345,6 @@ class FFCzBlob:
             tail += _ROI_MAGIC + struct.pack("<Q", len(self.roi_bound)) + self.roi_bound
         out = header + self.base_blob + se + fe + pw + tail
         if self.crc:
-            import zlib
-
             crcs = [zlib.crc32(out)] + [zlib.crc32(s) for s in (self.base_blob, se, fe, pw)]
             out += _CRC_MAGIC + struct.pack("<B", len(crcs)) + struct.pack(f"<{len(crcs)}I", *crcs)
         return out
@@ -422,8 +454,6 @@ class FFCzBlob:
         the per-section CRCs localize a mismatch to the corrupt section for
         the error message.
         """
-        import zlib
-
         tail_head = pos + 4 + 1
         if len(data) < tail_head:
             raise BlobCorruptError("corrupt FFCz blob: truncated CRC section")
@@ -443,6 +473,79 @@ class FFCzBlob:
 
     def nbytes(self) -> int:
         return len(self.to_bytes())
+
+
+# -- pencil envelope (FFSB) -------------------------------------------------
+#
+# One pencil-planned tensor: magic, version, <ddIB> E/Delta/block/ndim,
+# ndim * u64 shape, <QQQ> section lengths, sections, trailing u32 CRC32 of
+# every preceding byte.  A new wire format (no legacy writers), so the CRC
+# is unconditional.
+
+PENCIL_MAGIC = b"FFSB"
+_PENCIL_VERSION = 1
+PENCIL_HEADER = "<ddIB"
+
+
+def encode_pencil_blob(shape, base_blob: bytes, se, fe, plan, block: int) -> bytes:
+    """Assemble the ``FFSB`` envelope of one tensor from its
+    :class:`~repro.core.engine.PencilPlan`, base blob and edit streams."""
+    se_b, fe_b = se.to_bytes(), fe.to_bytes()
+    out = PENCIL_MAGIC + struct.pack("<B", _PENCIL_VERSION)
+    out += struct.pack(PENCIL_HEADER, plan.E, plan.Delta, block, len(shape))
+    out += struct.pack(f"<{len(shape)}Q", *shape)
+    out += struct.pack("<QQQ", len(base_blob), len(se_b), len(fe_b))
+    out += base_blob + se_b + fe_b
+    return out + struct.pack("<I", zlib.crc32(out))
+
+
+def decode_pencil_blob(data: bytes, base: Any) -> np.ndarray:
+    """Hardened decode of the pencil envelope (``FFSB``).
+
+    Every malformation — bad magic/version, truncation, section overrun,
+    CRC mismatch, codec garbage — raises :class:`BlobCorruptError`.
+    """
+    try:
+        if data[:4] != PENCIL_MAGIC:
+            raise BlobCorruptError("not an FFCz service pencil blob: bad magic")
+        if len(data) < 9 or data[4] != _PENCIL_VERSION:
+            raise BlobCorruptError(
+                f"unsupported service pencil blob version {data[4] if len(data) > 4 else '?'}"
+            )
+        if len(data) < 4 + 1 + 4:
+            raise BlobCorruptError("truncated service pencil blob")
+        body, (crc,) = data[:-4], struct.unpack_from("<I", data, len(data) - 4)
+        if zlib.crc32(body) != crc:
+            raise BlobCorruptError("corrupt service pencil blob: CRC mismatch")
+        off = 5
+        E, Delta, block, ndim = struct.unpack_from(PENCIL_HEADER, body, off)
+        off += struct.calcsize(PENCIL_HEADER)
+        if ndim > 16:
+            raise BlobCorruptError(f"corrupt service pencil blob: implausible rank {ndim}")
+        shape = struct.unpack_from(f"<{ndim}Q", body, off)
+        off += 8 * ndim
+        nb, ns, nf = struct.unpack_from("<QQQ", body, off)
+        off += struct.calcsize("<QQQ")
+        if len(body) != off + nb + ns + nf:
+            raise BlobCorruptError(
+                f"corrupt service pencil blob: {len(body)} bytes, sections want {off + nb + ns + nf}"
+            )
+        base_blob = body[off : off + nb]
+        se = EncodedEdits.from_bytes(body[off + nb : off + nb + ns])
+        fe = EncodedEdits.from_bytes(body[off + nb + ns : off + nb + ns + nf])
+        x_hat = np.asarray(base.decompress(base_blob), dtype=np.float32)
+        spat = decode_edits(se, E)
+        freq = decode_edits(fe, Delta)
+        complete = spat + np.fft.irfft(freq, n=block, axis=-1)
+        size = int(np.prod(shape)) if shape else 1
+        x = x_hat.astype(np.float64).reshape(-1) + complete.reshape(-1)[:size]
+        return x.reshape(shape).astype(np.float32)
+    except FFCzError:
+        raise
+    except Exception as e:  # noqa: BLE001 - untrusted bytes
+        raise BlobCorruptError(
+            f"corrupt service pencil blob: {type(e).__name__}: {e}", cause=e
+        ) from e
 
 
 def _irfftn(a: np.ndarray, shape) -> np.ndarray:
@@ -481,10 +584,7 @@ class FFCz:
         x32 = x.to_host() if sharded else np.asarray(x, dtype=np.float32)
 
         plan = self.engine.plan_field(x if sharded else x32, cfg)
-        base_blob = self.base.compress(x32, plan.E_proj)
-        x_hat = np.asarray(self.base.decompress(base_blob), dtype=np.float32)
-
-        eps0 = x_hat - x32
+        base_blob, eps0 = base_residual(self.base, x32, plan.E_proj)
         if sharded:
             eps0 = ShardedField(
                 eps0, x.mesh, x.axis_name, x.parity_requested, x.overlap_chunks
@@ -501,18 +601,7 @@ class FFCz:
         if sharded and x.padded_shape != x.shape:
             pad_meta = PadMeta(n_dev=x.n_dev, padded_shape=x.padded_shape)
 
-        blob = FFCzBlob(
-            base_blob=base_blob,
-            spat_edits=se,
-            freq_edits=fe,
-            E=plan.E,
-            Delta_scalar=plan.delta_scalar,
-            pointwise_delta=plan.pointwise_bytes(),
-            shape=plan.shape,
-            pad_meta=pad_meta,
-            roi_bound=plan.roi_bytes(),
-            crc=cfg.crc,
-        )
+        blob = FFCzBlob.from_plan(plan, base_blob, se, fe, crc=cfg.crc, pad_meta=pad_meta)
 
         stats = None
         if cfg.verify:

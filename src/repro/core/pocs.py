@@ -46,9 +46,9 @@ pluggable through :mod:`repro.kernels.rfft`:
   ``"packed"``  XLA's forward r2c (DUCC is already pack-trick fast) + the
                 pure-XLA pack-trick C2R inverse (``packed_irfftn``: one
                 Hermitian-mirror gather, twiddle recombination, half-length
-                complex ``ifftn``, de-interleave) — 1.2-1.3x per iteration
-                on CPU.  Composes with ``dist`` mode, where it swaps the
-                local last-axis c2r pass.
+                complex ``ifftn``, de-interleave); 1.2-1.3x per iteration
+                in a CPU measurement.  Composes with ``dist`` mode, where
+                it swaps the local last-axis c2r pass.
   ``"pallas"``  the packed transforms with fused Pallas epilogues: the
                 forward epilogue performs the f-cube clip, the pair-weighted
                 violation count AND the inverse pack twiddle in one VMEM

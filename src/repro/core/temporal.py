@@ -15,10 +15,11 @@ client that exploits the time axis, three ways:
                 accumulator from frame *t-1*'s converged edit spectrum
                 (``FFCzConfig.warm_start`` -> ``FieldPlan`` ->
                 :func:`repro.core.pocs.alternating_projection`, all three
-                backends).  Consecutive frames' base-compressor errors are
-                correlated, so the warm loop re-converges in a fraction of
-                the cold iteration count (the ``stream/warm-vs-cold`` bench
-                row).  Encoder-side only: it changes iteration counts, never
+                backends).  Where consecutive frames' base-compressor errors
+                are correlated, the warm loop re-converges in a fraction of
+                the cold iteration count (tests/test_temporal.py,
+                ``test_warm_chain_cuts_pocs_iterations``).  Encoder-side
+                only: it changes iteration counts, never
                 decodability or the bound guarantee, and ``warm_start=False``
                 is bitwise-neutral (cold frames byte-identical to FFCz).
   pencil mode   EEG-style channels-x-time data routes through the engine's
@@ -54,7 +55,7 @@ the predictor history (and the decode chain) restarts there, so a seek
 decode is bitwise identical to the full sequential decode — gated by
 tests/test_temporal.py.  Frame payloads are self-describing: whole-field
 frames are ordinary ``FFCZ`` blobs, pencil frames the ``FFSB`` envelope
-(defined here, shared with :class:`~repro.serving.ffcz_service.FFCzService`
+(:func:`~repro.core.ffcz.encode_pencil_blob`, the format of the service's
 pencil responses).
 
 Streaming submission goes through ``FFCzService.submit_stream`` — one stream
@@ -71,7 +72,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.edits import EncodedEdits, decode_edits
+from repro.compressors import base_residual
 from repro.core.engine import CorrectionEngine, default_engine
 from repro.core.errors import (
     BlobCorruptError,
@@ -79,91 +80,25 @@ from repro.core.errors import (
     InfeasibleBound,
     StreamStateError,
 )
-from repro.core.ffcz import FFCz, FFCzBlob, FFCzConfig
+from repro.core.ffcz import (
+    FFCz,
+    FFCzBlob,
+    FFCzConfig,
+    decode_pencil_blob,
+    encode_pencil_blob,
+)
 
 __all__ = [
+    "STREAM_MAGIC",
     "StreamEncoder",
     "TemporalCodec",
     "TemporalConfig",
     "TemporalStream",
-    "decode_pencil_blob",
 ]
-
-# -- pencil frame envelope (FFSB) -------------------------------------------
-#
-# One pencil-planned tensor: magic, version, <ddIB> E/Delta/block/ndim,
-# ndim * u64 shape, <QQQ> section lengths, sections, trailing u32 CRC32 of
-# every preceding byte.  A new wire format (no legacy writers), so the CRC
-# is unconditional.  Shared with the serving layer's pencil responses
-# (repro.serving.ffcz_service re-exports the decoder).
-
-_PENCIL_MAGIC = b"FFSB"
-_PENCIL_VERSION = 1
-_PENCIL_HEADER = "<ddIB"
-
-
-def _pencil_blob(shape, base_blob: bytes, se, fe, plan, block: int) -> bytes:
-    se_b, fe_b = se.to_bytes(), fe.to_bytes()
-    out = _PENCIL_MAGIC + struct.pack("<B", _PENCIL_VERSION)
-    out += struct.pack(_PENCIL_HEADER, plan.E, plan.Delta, block, len(shape))
-    out += struct.pack(f"<{len(shape)}Q", *shape)
-    out += struct.pack("<QQQ", len(base_blob), len(se_b), len(fe_b))
-    out += base_blob + se_b + fe_b
-    return out + struct.pack("<I", zlib.crc32(out))
-
-
-def decode_pencil_blob(data: bytes, base: Any) -> np.ndarray:
-    """Hardened decode of the pencil envelope (``FFSB``).
-
-    Every malformation — bad magic/version, truncation, section overrun,
-    CRC mismatch, codec garbage — raises :class:`BlobCorruptError`.
-    """
-    try:
-        if data[:4] != _PENCIL_MAGIC:
-            raise BlobCorruptError("not an FFCz service pencil blob: bad magic")
-        if len(data) < 9 or data[4] != _PENCIL_VERSION:
-            raise BlobCorruptError(
-                f"unsupported service pencil blob version {data[4] if len(data) > 4 else '?'}"
-            )
-        if len(data) < 4 + 1 + 4:
-            raise BlobCorruptError("truncated service pencil blob")
-        body, (crc,) = data[:-4], struct.unpack_from("<I", data, len(data) - 4)
-        if zlib.crc32(body) != crc:
-            raise BlobCorruptError("corrupt service pencil blob: CRC mismatch")
-        off = 5
-        E, Delta, block, ndim = struct.unpack_from(_PENCIL_HEADER, body, off)
-        off += struct.calcsize(_PENCIL_HEADER)
-        if ndim > 16:
-            raise BlobCorruptError(f"corrupt service pencil blob: implausible rank {ndim}")
-        shape = struct.unpack_from(f"<{ndim}Q", body, off)
-        off += 8 * ndim
-        nb, ns, nf = struct.unpack_from("<QQQ", body, off)
-        off += struct.calcsize("<QQQ")
-        if len(body) != off + nb + ns + nf:
-            raise BlobCorruptError(
-                f"corrupt service pencil blob: {len(body)} bytes, sections want {off + nb + ns + nf}"
-            )
-        base_blob = body[off : off + nb]
-        se = EncodedEdits.from_bytes(body[off + nb : off + nb + ns])
-        fe = EncodedEdits.from_bytes(body[off + nb + ns : off + nb + ns + nf])
-        x_hat = np.asarray(base.decompress(base_blob), dtype=np.float32)
-        spat = decode_edits(se, E)
-        freq = decode_edits(fe, Delta)
-        complete = spat + np.fft.irfft(freq, n=block, axis=-1)
-        size = int(np.prod(shape)) if shape else 1
-        x = x_hat.astype(np.float64).reshape(-1) + complete.reshape(-1)[:size]
-        return x.reshape(shape).astype(np.float32)
-    except FFCzError:
-        raise
-    except Exception as e:  # noqa: BLE001 - untrusted bytes
-        raise BlobCorruptError(
-            f"corrupt service pencil blob: {type(e).__name__}: {e}", cause=e
-        ) from e
-
 
 # -- stream container (FFCS) ------------------------------------------------
 
-_STREAM_MAGIC = b"FFCS"
+STREAM_MAGIC = b"FFCS"
 _STREAM_VERSION = 1
 # mode, predictor, keyframe_interval, n_frames, E, Delta, ndim
 _STREAM_HEADER = "<BBIIddB"
@@ -250,7 +185,7 @@ class TemporalStream:
     @staticmethod
     def from_bytes(data: bytes) -> "TemporalStream":
         try:
-            if data[:4] != _STREAM_MAGIC:
+            if data[:4] != STREAM_MAGIC:
                 raise BlobCorruptError("not an FFCS stream: bad magic")
             if len(data) < 5 or data[4] != _STREAM_VERSION:
                 raise BlobCorruptError(
@@ -347,7 +282,7 @@ class StreamEncoder:
     finalize-vs-append serialization depends on this invariant.
 
     ``frame_stats`` records, per frame, ``{"keyframe", "iterations",
-    "converged"}`` — the warm-vs-cold bench reads the iteration counts.
+    "converged"}``.
     :meth:`export_state` snapshots the committed state as plain data; the
     matching import hook is :meth:`TemporalCodec.restore_stream` (session
     crash recovery / spill-resume).
@@ -446,7 +381,7 @@ class StreamEncoder:
         if not self._frames:
             raise ValueError("cannot finish an empty stream")
         codec = self._codec
-        header = _STREAM_MAGIC + struct.pack("<B", _STREAM_VERSION)
+        header = STREAM_MAGIC + struct.pack("<B", _STREAM_VERSION)
         header += struct.pack(
             _STREAM_HEADER,
             _MODES.index(codec.stream.mode),
@@ -626,114 +561,83 @@ class TemporalCodec:
         """Keyframe: compress the frame itself; frame 0 also resolves the
         stream-level bounds (later keyframes pin them absolutely so the
         claim cannot drift with per-frame ranges)."""
+        if not first:
+            return self._compress_frame(x32, enc._E0, enc._Delta0, enc._block, warm)
         cfg = self.config
-        if self.stream.mode == "pencils":
-            if first:
-                plan = self.engine.plan_pencils(
-                    x32,
-                    E_rel=cfg.E_rel,
-                    Delta_rel=cfg.Delta_rel,
-                    E_abs=cfg.E_abs,
-                    Delta_abs=cfg.Delta_abs,
-                    block=enc._block,
-                    quant_bits=cfg.quant_bits,
-                )
-                if plan is None:
-                    raise InfeasibleBound(
-                        "stream spatial bound underflows float32 for frame 0", stage="plan"
-                    )
-                enc._E0, enc._Delta0 = plan.E, plan.Delta
-            payload, decoded, warm_next, iters, conv = self._compress_frame(
-                x32, enc._E0, enc._Delta0, enc._block, warm
-            )
-            return payload, decoded, warm_next, iters, conv
-        if first:
-            run_cfg = cfg
-        else:
-            run_cfg = dataclasses.replace(
-                cfg, E_abs=enc._E0, E_rel=None, Delta_abs=enc._Delta0, Delta_rel=None,
-                pspec_rel=None,
-            )
-        plan = self.engine.plan_field(x32, run_cfg)
-        if first:
+        if self.stream.mode == "field":
+            plan, frame = self._compress_field(x32, cfg, warm)
             enc._E0, enc._Delta0 = plan.E, float(plan.Delta)
-        base_blob = self.base.compress(x32, plan.E_proj)
-        x_hat = np.asarray(self.base.decompress(base_blob), dtype=np.float32)
-        result = self.engine.execute_field(x_hat - x32, plan, warm_freq=warm)
-        se, fe = self.engine.encode_field(result, plan)
-        blob = FFCzBlob(
-            base_blob=base_blob,
-            spat_edits=se,
-            freq_edits=fe,
-            E=plan.E,
-            Delta_scalar=plan.delta_scalar,
-            pointwise_delta=plan.pointwise_bytes(),
-            shape=plan.shape,
-            crc=cfg.crc,
+            return frame
+        plan = self.engine.plan_pencils(
+            x32,
+            E_rel=cfg.E_rel,
+            Delta_rel=cfg.Delta_rel,
+            E_abs=cfg.E_abs,
+            Delta_abs=cfg.Delta_abs,
+            block=enc._block,
+            quant_bits=cfg.quant_bits,
         )
-        decoded = self._ffcz.decompress(blob)
-        warm_next = np.asarray(result.freq, dtype=np.complex64)
-        return blob.to_bytes(), decoded, warm_next, int(result.iterations), bool(result.converged)
+        if plan is None:
+            raise InfeasibleBound(
+                "stream spatial bound underflows float32 for frame 0", stage="plan"
+            )
+        enc._E0, enc._Delta0 = plan.E, plan.Delta
+        return self._compress_frame(x32, enc._E0, enc._Delta0, enc._block, warm)
 
     def _compress_frame(self, data32, E_abs: float, Delta_abs: float, block: int, warm):
         """Compress one frame payload (a keyframe's field or a residual)
         against pinned absolute bounds; returns ``(payload, decoded,
         warm_next, iterations, converged)``."""
         cfg = self.config
-        if self.stream.mode == "pencils":
-            plan = self.engine.plan_pencils(
-                data32, E_abs=E_abs, Delta_abs=Delta_abs, block=block,
-                quant_bits=cfg.quant_bits,
+        if self.stream.mode == "field":
+            run_cfg = dataclasses.replace(
+                cfg, E_abs=float(E_abs), E_rel=None, Delta_abs=float(Delta_abs),
+                Delta_rel=None, pspec_rel=None,
             )
-            if plan is None:
-                raise InfeasibleBound(
-                    "stream spatial bound underflows float32 for this frame", stage="plan"
-                )
-            base_blob = self.base.compress(data32, plan.E_proj)
-            x_hat = np.asarray(self.base.decompress(base_blob), dtype=np.float32)
-            eps0 = x_hat - data32
-            tiles0 = self.engine.tile_f64(eps0, block)
-            _corr, edits, stats = self.engine.correct(
-                [eps0],
-                [plan.E_proj],
-                [plan.Delta_proj],
-                block=block,
-                max_iters=cfg.max_iters,
-                return_edits=True,
-                return_corrected=False,
-                fft_impl=cfg.fft_impl,
-                warm_freq=None if warm is None else [warm],
-            )
-            spat_t, freq_t = edits[0]
-            warm_next = np.asarray(freq_t, dtype=np.complex64)
-            se, fe, settled = self.engine.encode_pencils(spat_t, freq_t, tiles0, plan, codec="zlib")
-            payload = _pencil_blob(data32.shape, base_blob, se, fe, plan, block)
-            decoded = decode_pencil_blob(payload, self.base)
-            iters = int(np.max(np.asarray(stats.iterations))) if np.asarray(stats.iterations).size else 0
-            conv = bool(np.all(np.asarray(stats.converged))) and settled
-            return payload, decoded, warm_next, iters, conv
-        run_cfg = dataclasses.replace(
-            cfg, E_abs=float(E_abs), E_rel=None, Delta_abs=float(Delta_abs),
-            Delta_rel=None, pspec_rel=None,
+            return self._compress_field(data32, run_cfg, warm)[1]
+        plan = self.engine.plan_pencils(
+            data32, E_abs=E_abs, Delta_abs=Delta_abs, block=block,
+            quant_bits=cfg.quant_bits,
         )
-        plan = self.engine.plan_field(data32, run_cfg)
-        base_blob = self.base.compress(data32, plan.E_proj)
-        x_hat = np.asarray(self.base.decompress(base_blob), dtype=np.float32)
-        result = self.engine.execute_field(x_hat - data32, plan, warm_freq=warm)
+        if plan is None:
+            raise InfeasibleBound(
+                "stream spatial bound underflows float32 for this frame", stage="plan"
+            )
+        base_blob, eps0 = base_residual(self.base, data32, plan.E_proj)
+        tiles0 = self.engine.tile_f64(eps0, block)
+        _corr, edits, stats = self.engine.correct(
+            [eps0],
+            [plan.E_proj],
+            [plan.Delta_proj],
+            block=block,
+            max_iters=cfg.max_iters,
+            return_edits=True,
+            return_corrected=False,
+            fft_impl=cfg.fft_impl,
+            warm_freq=None if warm is None else [warm],
+        )
+        spat_t, freq_t = edits[0]
+        warm_next = np.asarray(freq_t, dtype=np.complex64)
+        se, fe, settled = self.engine.encode_pencils(spat_t, freq_t, tiles0, plan, codec="zlib")
+        payload = encode_pencil_blob(data32.shape, base_blob, se, fe, plan, block)
+        decoded = decode_pencil_blob(payload, self.base)
+        iters = int(np.max(np.asarray(stats.iterations))) if np.asarray(stats.iterations).size else 0
+        conv = bool(np.all(np.asarray(stats.converged))) and settled
+        return payload, decoded, warm_next, iters, conv
+
+    def _compress_field(self, x32, run_cfg: FFCzConfig, warm):
+        """One whole-field frame through the FFCz pipeline (plan, base pass,
+        execute from ``warm``, encode, blob, decode); returns ``(plan,
+        (payload, decoded, warm_next, iterations, converged))``."""
+        plan = self.engine.plan_field(x32, run_cfg)
+        base_blob, eps0 = base_residual(self.base, x32, plan.E_proj)
+        result = self.engine.execute_field(eps0, plan, warm_freq=warm)
         se, fe = self.engine.encode_field(result, plan)
-        blob = FFCzBlob(
-            base_blob=base_blob,
-            spat_edits=se,
-            freq_edits=fe,
-            E=plan.E,
-            Delta_scalar=plan.delta_scalar,
-            pointwise_delta=plan.pointwise_bytes(),
-            shape=plan.shape,
-            crc=cfg.crc,
-        )
+        blob = FFCzBlob.from_plan(plan, base_blob, se, fe, crc=run_cfg.crc)
         decoded = self._ffcz.decompress(blob)
         warm_next = np.asarray(result.freq, dtype=np.complex64)
-        return blob.to_bytes(), decoded, warm_next, int(result.iterations), bool(result.converged)
+        frame = (blob.to_bytes(), decoded, warm_next, int(result.iterations), bool(result.converged))
+        return plan, frame
 
     # -- decode ------------------------------------------------------------
 

@@ -7,16 +7,15 @@ that bracket each projection pair.  This package provides the two faster
   ``packed``   pack-trick transforms (:mod:`repro.kernels.rfft.ops`): an
                N-point real transform rides an N/2-point complex FFT via
                twiddle recombination (``twiddle_plan``), restricted to
-               even last axes (``supports_packed``); 1.16-1.20x per
-               iteration over the stock ``jnp.fft`` path, bitwise-gated
-               against :mod:`repro.kernels.rfft.ref`.
+               even last axes (``supports_packed``), bitwise-gated against
+               :mod:`repro.kernels.rfft.ref`.
   ``pallas``   the packed transform with the POCS projection epilogue
                fused into a Pallas kernel (:mod:`repro.kernels.rfft.kernel`):
                ``unpack_sclip_fused`` fuses C2R unpacking with the s-cube
                clip, ``fwd_epilogue_fused`` fuses R2C packing with the
                f-cube projection — eliminating one HBM round trip per loop
                iteration.  Compiles via Mosaic on TPU; interpret mode
-               elsewhere (priced honestly in BENCH_pocs.json).
+               elsewhere.
 
 Both impls produce the same per-block program across the local / batched /
 sharded backends, and both accept the temporal warm-start state

@@ -22,9 +22,9 @@ backends lift them for free).  Twiddles come from a cached plan registry
 (:func:`twiddle_plan`, keyed by last-axis length + dtype) so repeated shapes
 never rebuild them.
 
-Measured on the CI container CPU (512^2 / 128x128x64 POCS loop, the
-committed ``BENCH_pocs.json`` record): swapping ONLY the inverse for
-:func:`packed_irfftn` is 1.20x / 1.16x per iteration — the forward keeps
+On a CPU (512^2 / 128x128x64 POCS loop), swapping ONLY the inverse for
+:func:`packed_irfftn` was 1.20x / 1.16x per iteration; the speed on the
+chip is what ``BENCHMARK.json`` measures.  The forward keeps
 ``jnp.fft.rfftn`` because DUCC's r2c is already packed and beats
 :func:`packed_rfftn` (which is still provided: it is the fallback-free R2C
 for backends without a native r2c, and the oracle the tests pin).
@@ -136,10 +136,10 @@ def packed_irfftn(X: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
     """``jnp.fft.irfftn(X, s=shape)`` via the pack trick — the C2R fast path.
 
     One Hermitian-mirror gather, one elementwise twiddle recombination, one
-    complex ``ifftn`` at half the last-axis length, one de-interleave.  On
-    the CI CPU this replaces XLA's C2R custom call at ~1.55x; inside the
-    POCS loop the swap is worth 1.2-1.3x per iteration (see module
-    docstring).  ``shape`` is the true spatial shape (even last axis).
+    complex ``ifftn`` at half the last-axis length, one de-interleave.  In a
+    CPU measurement this replaced XLA's C2R custom call at ~1.55x and was
+    worth 1.2-1.3x per POCS iteration (see module docstring).  ``shape`` is
+    the true spatial shape (even last axis).
     """
     n = shape[-1]
     _, w_inv = twiddle_plan(n, "float32" if X.dtype == jnp.complex64 else "float64")

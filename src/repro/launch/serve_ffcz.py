@@ -16,13 +16,8 @@ points at a directory for file-backed WALs.
     # serial (un-pipelined) execution for A/B comparison:
     PYTHONPATH=src python -m repro.launch.serve_ffcz --requests 32 --pipeline-depth 1
 
-The offered-load sweep lives in ``benchmarks/bench_serve.py``, which reuses
-this module's flag groups (service, workload, faults) and adds
-``--arrival-rates`` / ``--requests-per-run`` on top:
-
-    PYTHONPATH=src python benchmarks/bench_serve.py --quick
-    PYTHONPATH=src python benchmarks/bench_serve.py \
-        --arrival-rates 5,20,80 --pencil-frac 0.75 --p-codec 0.1
+The on-chip benchmark (``perfbench/``) builds its service from this module's
+service and fault flag groups.
 """
 
 from __future__ import annotations
@@ -41,7 +36,7 @@ from repro.serving.ffcz_service import FFCzService, ServiceConfig
 
 
 def add_service_args(ap: argparse.ArgumentParser) -> None:
-    """Service-construction flags (shared with benchmarks/bench_serve.py)."""
+    """Service-construction flags (shared with the on-chip benchmark)."""
     ap.add_argument("--seed", type=int, default=0, help="workload + fault stream seed")
     ap.add_argument("--base", default="szlike", help="base compressor name")
     ap.add_argument("--max-batch", type=int, default=8, help="pencil requests fused per step")
@@ -63,7 +58,7 @@ def add_service_args(ap: argparse.ArgumentParser) -> None:
 
 
 def add_workload_args(ap: argparse.ArgumentParser) -> None:
-    """Synthetic-workload flags (shared with benchmarks/bench_serve.py)."""
+    """Synthetic-workload flags of this module's load generator."""
     ap.add_argument("--field-size", type=int, default=24, help="whole-field edge length")
     ap.add_argument("--e-rel", type=float, default=1e-3, help="relative spatial bound")
     ap.add_argument("--delta-rel", type=float, default=1e-3, help="relative spectral bound")

@@ -95,29 +95,29 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.compressors import base_residual
 from repro.core.engine import CorrectionEngine, default_engine
 from repro.core.errors import (
     DeadlineExceeded,
     FFCzError,
     InfeasibleBound,
     ResourceExhausted,
-    BlobCorruptError,
     classify_exception,
 )
-from repro.core.ffcz import FFCz, FFCzBlob, FFCzConfig
-from repro.core.spans import span
 
-# The pencil envelope (FFSB) lives in repro.core.temporal (the temporal codec
-# shares it for pencil-mode stream frames); re-exported here because the
-# service mints the format and callers decode through this module.
-from repro.core.temporal import (  # noqa: F401 - decode_pencil_blob re-exported
-    _PENCIL_MAGIC,
-    _STREAM_MAGIC,
-    TemporalCodec,
-    TemporalConfig,
-    _pencil_blob,
+# The pencil envelope (FFSB) lives in repro.core.ffcz beside FFCzBlob;
+# decode_pencil_blob is re-exported here because the service mints the
+# format and callers decode through this module.
+from repro.core.ffcz import (
+    PENCIL_MAGIC,
+    FFCz,
+    FFCzBlob,
+    FFCzConfig,
     decode_pencil_blob,
+    encode_pencil_blob,
 )
+from repro.core.spans import span
+from repro.core.temporal import STREAM_MAGIC, TemporalCodec, TemporalConfig
 from repro.serving.sessions import FileJournal, StreamSessionManager
 
 __all__ = [
@@ -822,21 +822,17 @@ class FFCzService:
 
             def _base():
                 self._fire("codec", req.uid)
-                blob = self.base.compress(x32, plan.E_proj)
-                return blob, np.asarray(self.base.decompress(blob), dtype=np.float32)
+                return base_residual(self.base, x32, plan.E_proj)
 
             with span("ffcz.base"):
-                base_blob, x_hat = self._attempt(req, "base", _base)
-                eps0 = x_hat - x32
+                base_blob, eps0 = self._attempt(req, "base", _base)
             # attempt 1 of the first ladder rung dispatches here so the device
             # starts while the previous unit is still encoding; failures are
             # stashed raw and re-raised inside the back half's ladder, which
             # owns classification and the retry budget
             handle = exc = None
             try:
-                handle = self._dispatch_field(
-                    req, eps0, dataclasses.replace(plan, fft_impl=plan.fft_impl)
-                )
+                handle = self._dispatch_field(req, eps0, plan)
             except Exception as e:  # noqa: BLE001 - re-raised in the back half
                 exc = e
             return _Staged(
@@ -876,19 +872,9 @@ class FFCzService:
                 cfg: FFCzConfig = req.cfg
                 with span("ffcz.encode"):
                     se, fe = self._attempt(req, "encode", _encode)
-                    blob = FFCzBlob(
-                        base_blob=staged.base_blob,
-                        spat_edits=se,
-                        freq_edits=fe,
-                        E=run_plan.E,
-                        Delta_scalar=run_plan.delta_scalar,
-                        pointwise_delta=run_plan.pointwise_bytes(),
-                        shape=run_plan.shape,
-                        roi_bound=run_plan.roi_bytes(),
-                        crc=cfg.crc,
-                    )
+                    blob = FFCzBlob.from_plan(run_plan, staged.base_blob, se, fe, crc=cfg.crc)
                     payload = blob.to_bytes()
-                if getattr(cfg, "verify_pspec", False) and cfg.pspec_rel is not None:
+                if cfg.verify_pspec and cfg.pspec_rel is not None:
                     # derived-quantity recheck rides the encode stage: decode
                     # the assembled blob and measure the live-shell power-
                     # spectrum ratio in float64 (opt-in; two host FFTs)
@@ -1012,12 +998,10 @@ class FFCzService:
 
                 def _base(x=x32, p=plan, r=req):
                     self._fire("codec", r.uid)
-                    blob = self.base.compress(x, p.E_proj)
-                    return blob, np.asarray(self.base.decompress(blob), dtype=np.float32)
+                    return base_residual(self.base, x, p.E_proj)
 
                 with span("ffcz.base"):
-                    base_blob, x_hat = self._attempt(req, "base", _base)
-                    eps0 = x_hat - x32
+                    base_blob, eps0 = self._attempt(req, "base", _base)
                 tiles0 = self.engine.tile_f64(eps0, self.config.block)
                 work.append((req, base_blob, eps0, tiles0, plan))
             except FFCzError as err:
@@ -1119,7 +1103,9 @@ class FFCzService:
                         se, fe, settled = self._attempt(req, "encode", _encode)
                         req.converged = req.converged and settled
                         x = np.asarray(req.payload)
-                        payload = _pencil_blob(x.shape, base_blob, se, fe, plan, self.config.block)
+                        payload = encode_pencil_blob(
+                            x.shape, base_blob, se, fe, plan, self.config.block
+                        )
                         out.append(self._complete(req, payload))
                     except FFCzError as err:
                         out.append(self._reject(req, err))
@@ -1212,10 +1198,10 @@ class FFCzService:
         try:
             self._check_deadline(req)
             data: bytes = req.payload
-            if data[:4] == _STREAM_MAGIC:
+            if data[:4] == STREAM_MAGIC:
                 codec = TemporalCodec(self.base, FFCzConfig(), engine=self.engine)
                 return self._complete(req, np.stack(codec.decompress_stream(data)))
-            if data[:4] == _PENCIL_MAGIC:
+            if data[:4] == PENCIL_MAGIC:
                 return self._complete(req, decode_pencil_blob(data, self.base))
             # decode consumes no bound config — the blob carries its bounds
             ffcz = FFCz(self.base, FFCzConfig(), engine=self.engine)

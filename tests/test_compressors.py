@@ -5,7 +5,7 @@ import pytest
 from _hyp import given, settings, st  # hypothesis or skip-stubs (requirements-dev.txt)
 
 from repro.coding import lossless
-from repro.compressors import get_compressor
+from repro.compressors import base_residual, get_compressor
 
 NAMES = ["szlike", "zfplike", "sperrlike", "identity"]
 
@@ -54,6 +54,20 @@ class TestBoundContract:
         c = get_compressor(name)
         xh = c.decompress(c.compress(x, E))
         assert np.abs(xh - x).max() <= E * (1 + 1e-5)
+
+
+class TestBaseResidual:
+    @pytest.mark.parametrize("name", ["szlike", "zfplike", "identity"])
+    def test_same_blob_and_residual_as_the_round_trip(self, name):
+        x = _field((33, 21), seed=4)
+        E = 1e-3 * float(np.ptp(x))
+        c = get_compressor(name)
+        blob, eps0 = base_residual(c, x, E)
+        ref_blob = c.compress(x, E)
+        ref_eps0 = np.asarray(c.decompress(ref_blob), dtype=np.float32) - x
+        assert blob == ref_blob
+        assert eps0.dtype == np.float32
+        assert np.array_equal(eps0.view(np.uint32), ref_eps0.view(np.uint32))
 
 
 class TestRatioOrdering:

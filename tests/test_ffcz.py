@@ -8,7 +8,9 @@ import pytest
 from repro.compressors import get_compressor
 from repro.core.edits import decode_edits, encode_edits
 from repro.core.ffcz import FFCz, FFCzBlob, FFCzConfig
+from repro.core.temporal import TemporalCodec, TemporalConfig, TemporalStream
 from repro.data.fields import make_field
+from repro.serving.ffcz_service import FFCzService, ServiceConfig
 
 
 @pytest.fixture(scope="module")
@@ -121,3 +123,25 @@ class TestNonConvergenceSurfacing:
         _, blob = c.roundtrip(nyx)
         assert blob.stats.converged is True
         assert blob.stats.final_violations == 0
+
+
+class TestOnePipeline:
+    def test_every_writer_mints_the_same_blob(self, nyx):
+        """FFCz.compress, the service's whole-field request and frame 0 of a
+        field stream run one pipeline: the same config and base give the same
+        bytes from each writer."""
+        cfg = FFCzConfig(E_rel=1e-3, Delta_rel=1e-3, max_iters=1000)
+        x = np.ascontiguousarray(nyx)
+        direct = FFCz(get_compressor("szlike"), cfg).compress(x).to_bytes()
+
+        svc = FFCzService(get_compressor("szlike"), config=ServiceConfig(pipeline_depth=1))
+        uid = svc.submit_compress(x, cfg)
+        resp = svc.drain()[uid]
+        svc.close()
+        assert resp.ok, resp.error
+
+        codec = TemporalCodec(get_compressor("szlike"), cfg, TemporalConfig(mode="field"))
+        frame0 = TemporalStream.from_bytes(codec.compress_stream([x])).frame_payload(0)
+
+        assert resp.payload == direct
+        assert frame0 == direct
