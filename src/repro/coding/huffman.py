@@ -19,7 +19,6 @@ Wire format (little-endian):
 
 from __future__ import annotations
 
-import heapq
 import struct
 
 import numpy as np
@@ -32,24 +31,41 @@ DECODE_CHUNK_BITS = 1 << 20
 
 
 def _code_lengths(freqs: np.ndarray) -> np.ndarray:
-    """Huffman code lengths from symbol frequencies (heap merge)."""
+    """Huffman code lengths from symbol frequencies (two-queue merge).
+
+    Each merge takes the two least nodes by ``(frequency, tiebreak)``: a leaf
+    breaks ties by its symbol index, an internal node by its creation order
+    after every leaf.  Leaves sorted by (frequency, index) form one queue and
+    internal nodes, whose frequencies never decrease, another, so each merge
+    compares the two heads: a leaf goes first on equal frequency.  That is
+    the order a heap keyed ``(frequency, tiebreak)`` pops, so the tree, the
+    lengths and the canonical codes are the heap's.  The lengths are the
+    leaves' depths, read down the parent links.
+    """
     n = len(freqs)
     if n == 1:
         return np.array([1], dtype=np.uint8)
-    # heap entries: (freq, tiebreak, set-of-symbol-indices)
-    heap = [(int(f), i, [i]) for i, f in enumerate(freqs)]
-    heapq.heapify(heap)
-    lengths = np.zeros(n, dtype=np.int64)
-    tiebreak = n
-    while len(heap) > 1:
-        fa, _, sa = heapq.heappop(heap)
-        fb, _, sb = heapq.heappop(heap)
-        for s in sa:
-            lengths[s] += 1
-        for s in sb:
-            lengths[s] += 1
-        heapq.heappush(heap, (fa + fb, tiebreak, sa + sb))
-        tiebreak += 1
+    order = np.argsort(freqs, kind="stable")
+    # nodes 0..n-1 are the sorted leaves, n.. the internal nodes in creation order
+    node_f = np.asarray(freqs, dtype=np.int64)[order].tolist() + [0] * (n - 1)
+    parent = [0] * (2 * n - 1)
+    li, ii = 0, n  # heads of the leaf and internal queues
+    for new in range(n, 2 * n - 1):
+        if li < n and (ii == new or node_f[li] <= node_f[ii]):
+            a, li = li, li + 1
+        else:
+            a, ii = ii, ii + 1
+        if li < n and (ii == new or node_f[li] <= node_f[ii]):
+            b, li = li, li + 1
+        else:
+            b, ii = ii, ii + 1
+        node_f[new] = node_f[a] + node_f[b]
+        parent[a] = parent[b] = new
+    depth = [0] * (2 * n - 1)  # the root, node 2n-2, sits at depth 0
+    for node in range(2 * n - 3, -1, -1):  # a parent is made after its children
+        depth[node] = depth[parent[node]] + 1
+    lengths = np.empty(n, dtype=np.int64)
+    lengths[order] = depth[:n]
     return lengths.astype(np.uint8)
 
 
@@ -57,18 +73,21 @@ def _canonical_codes(lengths: np.ndarray) -> np.ndarray:
     """Canonical Huffman code values (uint64) given code lengths.
 
     Symbols are ranked by (length, symbol-index); codes assigned in canonical
-    order so the decoder only needs the lengths.
+    order so the decoder only needs the lengths: the first code of length
+    ``L`` is ``(first[L-1] + count[L-1]) << 1``, and the codes of one length
+    count up from it in rank order.
     """
-    order = np.lexsort((np.arange(len(lengths)), lengths))
-    codes = np.zeros(len(lengths), dtype=np.uint64)
-    code = 0
-    prev_len = int(lengths[order[0]])
-    for rank, sym in enumerate(order):
-        ln = int(lengths[sym])
-        if rank > 0:
-            code = (code + 1) << (ln - prev_len)
-        codes[sym] = code
-        prev_len = ln
+    lengths = np.asarray(lengths, dtype=np.int64)
+    order = np.argsort(lengths, kind="stable")
+    ranked = lengths[order]
+    count = np.bincount(ranked)
+    first = [0] * len(count)
+    for ln in range(1, len(count)):
+        first[ln] = (first[ln - 1] + int(count[ln - 1])) << 1
+    start = np.concatenate(([0], np.cumsum(count)[:-1]))  # rank of each length's first symbol
+    rank = np.arange(len(lengths), dtype=np.uint64)
+    codes = np.empty(len(lengths), dtype=np.uint64)
+    codes[order] = np.asarray(first, dtype=np.uint64)[ranked] + (rank - start[ranked].astype(np.uint64))
     return codes
 
 

@@ -27,6 +27,7 @@ from repro.coding.bitpack import pack_bits, unpack_bits
 from repro.coding.lossless import lossless_compress, lossless_decompress
 from repro.coding.quantize import DEFAULT_QUANT_BITS, dequantize_uniform, quantize_uniform
 from repro.core.errors import BlobCorruptError
+from repro.core.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,18 +127,24 @@ def encode_edits(
     is_complex = np.iscomplexobj(edits)
     flat = edits.ravel()
     bound = np.asarray(bound, dtype=np.float64)
-    bound = bound.ravel() if bound.ndim else bound
-    if is_complex:
-        codes_full = np.stack(
-            [quantize_uniform(flat.real, bound, m), quantize_uniform(flat.imag, bound, m)],
-            axis=-1,
-        )
-        active = np.flatnonzero(codes_full.any(axis=-1))
-        compact = codes_full[active].ravel()  # interleaved Re/Im codes
-    else:
-        codes_full = quantize_uniform(flat, bound, m)
-        active = np.flatnonzero(codes_full)
-        compact = codes_full[active]
+    # a zero edit quantizes to code 0, so only the nonzero components are
+    # quantized; the active ones are those whose codes are not all zero
+    nonzero = np.flatnonzero(flat)
+    b = bound.ravel()[nonzero] if bound.size > 1 else bound
+    vals = flat[nonzero]
+    with span("ffcz.quantize", n=int(flat.size), nonzero=int(nonzero.size)) as sp:
+        if is_complex:
+            codes = np.stack(
+                [quantize_uniform(vals.real, b, m), quantize_uniform(vals.imag, b, m)], axis=-1
+            )
+            keep = codes.any(axis=-1)
+            compact = codes[keep].ravel()  # interleaved Re/Im codes
+        else:
+            codes = quantize_uniform(vals, b, m)
+            keep = codes != 0
+            compact = codes[keep]
+        active = nonzero[keep]
+        sp.set_metadata(active=int(active.size))
     flags = np.zeros(flat.size, dtype=bool)
     flags[active] = True
     # Flag bitmaps are overwhelmingly sparse (Fig. 5) — deflating them takes

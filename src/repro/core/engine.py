@@ -1351,9 +1351,13 @@ class CorrectionEngine:
         its conjugate-pair multiplicity.
         """
         k_s = int(np.count_nonzero(result.spat))
-        pair_w = np.broadcast_to(np.asarray(rfft_pair_weights(plan.shape)), result.freq.shape)
-        delta_b = np.broadcast_to(np.asarray(plan.Delta), result.freq.shape)
-        sum_active_delta = float(np.sum((pair_w * delta_b)[result.freq != 0]))
+        shape_f = np.shape(result.freq)
+        # the active components alone, in C order: the same terms, summed
+        # in the same order, as over the dense mask
+        at = np.unravel_index(np.flatnonzero(result.freq), shape_f)
+        pair_w = np.broadcast_to(np.asarray(rfft_pair_weights(plan.shape)), shape_f)[at]
+        delta_b = np.broadcast_to(np.asarray(plan.Delta), shape_f)[at]
+        sum_active_delta = float(np.sum(pair_w * delta_b))
         n = int(np.prod(plan.shape)) if plan.shape else 1
         m_s, m_f = adaptive_quant_bits(
             plan.quant_bits,

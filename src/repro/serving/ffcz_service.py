@@ -95,7 +95,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.compressors import base_residual
+from repro.compressors import base_residual, keeps_recon
 from repro.core.engine import CorrectionEngine, default_engine
 from repro.core.errors import (
     DeadlineExceeded,
@@ -293,6 +293,10 @@ class FFCzService:
             "bisects": 0,
             "timeouts": 0,
             "buffer_evictions": 0,
+            # how each request's base pass got eps0: from the reconstruction
+            # the codec kept, or by decoding its blob
+            "base_recon_kept": 0,
+            "base_recon_decoded": 0,
         }
         # cumulative stage clocks (seconds): front = plan/base/pack/dispatch
         # on the scheduler thread; execute = the device fence, the edit
@@ -813,6 +817,15 @@ class FFCzService:
             self._fire("oom", req.uid)
             return self.engine.execute_field_async(eps0, run_plan)
 
+    def _base_pass(self, req: _Request, base_fn):
+        """One request's base pass under ``ffcz.base``, counted by how it
+        got ``eps0``."""
+        recon = "kept" if keeps_recon(self.base) else "decoded"
+        with span("ffcz.base", recon=recon):
+            out = self._attempt(req, "base", base_fn)
+        self._count(f"base_recon_{recon}")
+        return out
+
     def _front_field(self, req: _Request) -> _Staged:
         try:
             cfg: FFCzConfig = req.cfg
@@ -824,8 +837,7 @@ class FFCzService:
                 self._fire("codec", req.uid)
                 return base_residual(self.base, x32, plan.E_proj)
 
-            with span("ffcz.base"):
-                base_blob, eps0 = self._attempt(req, "base", _base)
+            base_blob, eps0 = self._base_pass(req, _base)
             # attempt 1 of the first ladder rung dispatches here so the device
             # starts while the previous unit is still encoding; failures are
             # stashed raw and re-raised inside the back half's ladder, which
@@ -1000,8 +1012,7 @@ class FFCzService:
                     self._fire("codec", r.uid)
                     return base_residual(self.base, x, p.E_proj)
 
-                with span("ffcz.base"):
-                    base_blob, eps0 = self._attempt(req, "base", _base)
+                base_blob, eps0 = self._base_pass(req, _base)
                 tiles0 = self.engine.tile_f64(eps0, self.config.block)
                 work.append((req, base_blob, eps0, tiles0, plan))
             except FFCzError as err:

@@ -98,6 +98,90 @@ def _decode_walk_reference(data: bytes) -> np.ndarray:
     return alphabet[out]
 
 
+def _heap_code_lengths(freqs):
+    """Code lengths by the heap merge: pop the two least ``(freq, tiebreak)``
+    entries (a leaf's tiebreak is its index, an internal node's its creation
+    order after every leaf) and deepen every symbol under both."""
+    import heapq
+
+    n = len(freqs)
+    if n == 1:
+        return np.array([1], dtype=np.uint8)
+    heap = [(int(f), i, [i]) for i, f in enumerate(freqs)]
+    heapq.heapify(heap)
+    lengths = np.zeros(n, dtype=np.int64)
+    tiebreak = n
+    while len(heap) > 1:
+        fa, _, sa = heapq.heappop(heap)
+        fb, _, sb = heapq.heappop(heap)
+        lengths[sa + sb] += 1
+        heapq.heappush(heap, (fa + fb, tiebreak, sa + sb))
+        tiebreak += 1
+    return lengths.astype(np.uint8)
+
+
+def _sequential_canonical_codes(lengths):
+    """Canonical codes by the rank-order walk: each code is the one before
+    plus one, shifted left by the growth in length."""
+    order = np.lexsort((np.arange(len(lengths)), lengths))
+    codes = np.zeros(len(lengths), dtype=np.uint64)
+    code, prev = 0, int(lengths[order[0]])
+    for rank, sym in enumerate(order):
+        ln = int(lengths[sym])
+        if rank:
+            code = (code + 1) << (ln - prev)
+        codes[sym], prev = code, ln
+    return codes
+
+
+COUNT_TABLES = {
+    "random": lambda rng: rng.integers(1, 5000, 700),
+    "tied": lambda rng: rng.integers(1, 4, 900),
+    "all-equal": lambda rng: np.full(64, 7),
+    "skewed": lambda rng: np.concatenate([[10**9], rng.integers(1, 3, 300), [10**6, 10**3]]),
+    "geometric": lambda rng: 2 ** np.arange(40),
+    "one-symbol": lambda rng: np.array([5]),
+    "two-symbol": lambda rng: np.array([3, 3]),
+    "two-symbol-uneven": lambda rng: np.array([1, 10**6]),
+    "large-alphabet": lambda rng: np.concatenate(
+        [rng.integers(1, 3, 60_000), (rng.pareto(0.6, 2_000) * 50 + 1).astype(np.int64)]
+    ),
+}
+
+
+class TestLinearCodeLengths:
+    """The two-queue code lengths and the canonical codes equal the heap
+    merge's and the rank-order walk's, so the encoded bytes are unchanged."""
+
+    @pytest.mark.parametrize("table", sorted(COUNT_TABLES))
+    def test_lengths_and_codes_match_the_heap(self, table, rng):
+        from repro.coding.huffman import _canonical_codes, _code_lengths
+
+        counts = np.asarray(COUNT_TABLES[table](rng), dtype=np.int64)
+        lengths = _code_lengths(counts)
+        assert lengths.dtype == np.uint8
+        assert np.array_equal(lengths, _heap_code_lengths(counts))
+        assert np.array_equal(_canonical_codes(lengths), _sequential_canonical_codes(lengths))
+
+    @given(st.lists(st.integers(1, 50), min_size=1, max_size=120))
+    @settings(max_examples=60, deadline=None)
+    def test_lengths_match_the_heap_property(self, counts):
+        from repro.coding.huffman import _canonical_codes, _code_lengths
+
+        counts = np.array(counts, dtype=np.int64)
+        lengths = _code_lengths(counts)
+        assert np.array_equal(lengths, _heap_code_lengths(counts))
+        assert np.array_equal(_canonical_codes(lengths), _sequential_canonical_codes(lengths))
+
+    def test_codes_of_any_length_table(self, rng):
+        """The decoder builds codes from lengths it reads, any table at all."""
+        from repro.coding.huffman import _canonical_codes
+
+        for n in (1, 2, 17, 300):
+            lengths = rng.integers(0, 20, n).astype(np.uint8)
+            assert np.array_equal(_canonical_codes(lengths), _sequential_canonical_codes(lengths))
+
+
 class TestHuffmanVectorizedDecode:
     """ISSUE 5 satellite: the decode LUT walk is numpy-vectorized
     (windowed u32 reads + pointer-doubling chain) and byte-exact."""

@@ -1,11 +1,13 @@
 """Base compressors: the pointwise L-inf contract, all dims and dtypes."""
 
+import struct
+
 import numpy as np
 import pytest
 from _hyp import given, settings, st  # hypothesis or skip-stubs (requirements-dev.txt)
 
 from repro.coding import lossless
-from repro.compressors import base_residual, get_compressor
+from repro.compressors import base_residual, get_compressor, keeps_recon
 
 NAMES = ["szlike", "zfplike", "sperrlike", "identity"]
 
@@ -97,3 +99,108 @@ class TestChunkedBaseCodec:
         assert blob != serial
         assert np.array_equal(c.decompress(blob), c.decompress(serial))
         assert abs(len(blob) - len(serial)) <= 0.002 * len(serial)
+
+
+# -- the strided interpolation passes against the np.ix_ index grids --------
+
+
+def _ix_pass_indices(shape, stride, axis):
+    """One pass's ``np.ix_`` grids (target, left, right), as the passes were
+    indexed before they ran on strided views."""
+    n_a = shape[axis]
+    tgt = np.arange(stride, n_a, 2 * stride)
+    if tgt.size == 0:
+        return None
+    left = tgt - stride
+    right = np.where(tgt + stride < n_a, tgt + stride, tgt - stride)
+    others = [np.arange(0, n, stride if a < axis else 2 * stride) for a, n in enumerate(shape) if a != axis]
+
+    def with_axis(ax_idx):
+        return np.ix_(*others[:axis], ax_idx, *others[axis:])
+
+    return with_axis(tgt), with_axis(left), with_axis(right)
+
+
+def _ix_reference(codec, x, E):
+    """szlike's blob and decode by ``np.ix_`` gathers and scatters."""
+    from repro.coding.lossless import lossless_compress
+    from repro.compressors.szlike import _coarse_stride, _pass_schedule
+
+    shape, step = x.shape, 2.0 * E
+    r = np.zeros(shape, dtype=np.float64)
+    coarse_ix = np.ix_(*[np.arange(0, n, _coarse_stride(shape)) for n in shape])
+    coarse_vals = x[coarse_ix].astype(np.float32)
+    r[coarse_ix] = coarse_vals
+    codes_all = []
+    for stride, axis in _pass_schedule(shape):
+        idx = _ix_pass_indices(shape, stride, axis)
+        if idx is None:
+            continue
+        tgt, left, right = idx
+        pred = 0.5 * (r[left] + r[right])
+        codes = np.rint((x[tgt].astype(np.float64) - pred) / step)
+        r[tgt] = pred + codes * step
+        codes_all.append(codes.astype(np.int64).ravel())
+    codes_flat = np.concatenate(codes_all) if codes_all else np.zeros(0, dtype=np.int64)
+    header = struct.pack("<dB", E, x.ndim) + struct.pack(f"<{x.ndim}Q", *shape)
+    header += struct.pack("<I", coarse_vals.size) + coarse_vals.tobytes()
+    return header + lossless_compress(codes_flat, codec=codec), r.astype(np.float32)
+
+
+STRIDED_SHAPES = [
+    (1,), (2,), (257,), (256,),
+    (1, 7), (2, 2), (33, 21), (32, 64), (2, 5),
+    (17, 12, 9), (16, 16, 16), (1, 2, 9), (12, 1, 31), (9, 2, 1),
+]
+
+
+class TestStridedPasses:
+    @pytest.mark.parametrize("shape", STRIDED_SHAPES)
+    @pytest.mark.parametrize("codec", ["zlib", "huffman+zlib"])
+    def test_blob_and_decode_match_the_ix_passes(self, shape, codec):
+        x = _field(shape, seed=len(shape) + shape[-1])
+        E = 1e-3 * float(np.ptp(x)) + 1e-6
+        ref_blob, ref_dec = _ix_reference(codec, x, E)
+        c = get_compressor("szlike", codec=codec)
+        blob, recon = c.compress_with_recon(x, E)
+        assert blob == ref_blob == c.compress(x, E)
+        dec = c.decompress(blob)
+        assert dec.dtype == np.float32 and dec.shape == x.shape
+        assert np.array_equal(dec.view(np.uint32), ref_dec.view(np.uint32))
+        # the kept reconstruction is what the decoder rebuilds, bit for bit
+        assert np.array_equal(recon.astype(np.float32).view(np.uint32), dec.view(np.uint32))
+
+
+class _DecodeOnly:
+    """szlike's blob behind a codec that keeps no reconstruction."""
+
+    def __init__(self):
+        self._inner = get_compressor("szlike")
+
+    def compress(self, x, E):
+        return self._inner.compress(x, E)
+
+    def decompress(self, blob):
+        return self._inner.decompress(blob)
+
+
+class TestKeptReconstruction:
+    @pytest.mark.parametrize("shape", [(257,), (33, 21), (17, 12, 9)])
+    def test_kept_eps0_is_the_decoded_eps0(self, shape):
+        x = _field(shape, seed=9)
+        E = 1e-3 * float(np.ptp(x))
+        kept_blob, kept = base_residual(get_compressor("szlike"), x, E)
+        dec_blob, decoded = base_residual(_DecodeOnly(), x, E)
+        assert kept_blob == dec_blob
+        assert kept.dtype == decoded.dtype == np.float32
+        assert np.array_equal(kept.view(np.uint32), decoded.view(np.uint32))
+
+    @pytest.mark.parametrize("name,kept", [("szlike", True), ("zfplike", False), ("sperrlike", False), ("identity", False)])
+    def test_which_codecs_keep_it(self, name, kept, monkeypatch):
+        c = get_compressor(name)
+        assert keeps_recon(c) is kept
+        calls = []
+        real = c.decompress
+        monkeypatch.setattr(c, "decompress", lambda blob: calls.append(1) or real(blob))
+        base_residual(c, _field((33, 21)), 1e-2)
+        assert len(calls) == (0 if kept else 1)

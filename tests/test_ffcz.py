@@ -75,6 +75,87 @@ class TestSerialization:
         assert np.abs(back - edits).max() <= 0.2 * 2.0**-16 * np.sqrt(2) * (1 + 1e-9)
 
 
+def _dense_encode_edits(edits, bound, m, codec="huffman+zlib", half_spectrum=False):
+    """``encode_edits`` by quantizing every component, zero or not."""
+    import zlib
+
+    from repro.coding.bitpack import pack_bits
+    from repro.coding.lossless import lossless_compress
+    from repro.coding.quantize import quantize_uniform
+    from repro.core.edits import EncodedEdits
+
+    flat = np.asarray(edits).ravel()
+    bound = np.asarray(bound, dtype=np.float64)
+    bound = bound.ravel() if bound.ndim else bound
+    if np.iscomplexobj(flat):
+        codes = np.stack([quantize_uniform(flat.real, bound, m), quantize_uniform(flat.imag, bound, m)], -1)
+        active = np.flatnonzero(codes.any(axis=-1))
+        compact = codes[active].ravel()
+    else:
+        codes = quantize_uniform(flat, bound, m)
+        active = np.flatnonzero(codes)
+        compact = codes[active]
+    flags = np.zeros(flat.size, dtype=bool)
+    flags[active] = True
+    return EncodedEdits(
+        shape=tuple(np.shape(edits)),
+        is_complex=np.iscomplexobj(flat),
+        flags=zlib.compress(pack_bits(flags), 6),
+        payload=lossless_compress(compact, codec=codec),
+        n_active=int(active.size),
+        quant_bits=m,
+        half_spectrum=half_spectrum,
+    )
+
+
+def _sparse_stream(rng, shape, is_complex, density, scale):
+    edits = np.zeros(shape, dtype=np.complex128 if is_complex else np.float64)
+    k = max(1, int(density * edits.size))
+    at = rng.choice(edits.size, k, replace=False)
+    vals = rng.standard_normal(k) * scale
+    if is_complex:
+        vals = vals + 1j * rng.standard_normal(k) * scale * (rng.random(k) < 0.5)
+    edits.flat[at] = vals
+    return edits
+
+
+# (shape, density, value scale against the bound): a scale of 1e-7 makes
+# many nonzero values quantize to code 0; density 0 is an all-zero stream
+EDIT_STREAMS = [
+    ((4000,), 0.02, 0.3),
+    ((24, 17, 9), 0.05, 0.5),
+    ((24, 17, 9), 0.05, 1e-7),
+    ((30, 40), 0.5, 2.0),
+    ((30, 40), 0.0, 1.0),
+]
+
+
+class TestActiveSetEncode:
+    """``encode_edits`` quantizes only the nonzero components; its streams
+    are the dense quantizer's, byte for byte."""
+
+    @pytest.mark.parametrize("shape,density,scale", EDIT_STREAMS)
+    @pytest.mark.parametrize("is_complex", [False, True])
+    @pytest.mark.parametrize("per_component", [False, True])
+    @pytest.mark.parametrize("codec", ["huffman+zlib", "zlib"])
+    def test_matches_the_dense_encoder(self, shape, density, scale, is_complex, per_component, codec):
+        rng = np.random.default_rng(sum(shape) + int(is_complex))
+        edits = _sparse_stream(rng, shape, is_complex, density, 0.05 * scale) if density else np.zeros(
+            shape, dtype=np.complex128 if is_complex else np.float64
+        )
+        bound = rng.uniform(0.02, 0.08, shape) if per_component else 0.05
+        if per_component:
+            bound.flat[:: 7] = 0.0  # zero-width bounds quantize to code 0
+        enc = encode_edits(edits, bound, m=18, codec=codec, half_spectrum=is_complex)
+        ref = _dense_encode_edits(edits, bound, 18, codec=codec, half_spectrum=is_complex)
+        assert enc == ref
+        assert enc.to_bytes() == ref.to_bytes()
+        if scale == 1e-7 or not density:
+            assert enc.n_active == 0
+        back = decode_edits(enc, bound)
+        assert np.array_equal(back, decode_edits(ref, bound))
+
+
 class TestEditsAreSparse:
     def test_edit_overhead_modest(self, nyx):
         """Paper Obs. 1: edits cost a modest fraction on top of the base."""

@@ -103,6 +103,7 @@ def traced(tmp_path_factory):
         "field": field,
         "pencils": pencils,
         "rounds": len(rounds),
+        "counters": dict(svc.counters),
     }
 
 
@@ -179,6 +180,34 @@ def test_polish_spans_carry_slabs_and_clip_counts(traced):
         if sp[0] == "ffcz.polish.round":
             assert set(sp[4]) == {"f_clipped", "s_clipped"}
             assert sp[4]["f_clipped"] > 0 and sp[4]["s_clipped"] >= 0
+
+
+def test_quantize_spans_inside_encode(traced):
+    """The field's ENCODE quantizes two streams, each in one ``ffcz.quantize``
+    that says how many components it holds, how many are nonzero and how
+    many of those are active (their codes are not all zero)."""
+    from repro.core.ffcz import FFCzBlob
+
+    spans, uid = traced["spans"], traced["field"]
+    back, _inner = _unit(spans, "ffcz.back", uid)
+    (encode,) = [sp for sp in _inside(spans, back) if sp[0] == "ffcz.encode"]
+    quant = sorted((sp for sp in _inside(spans, encode) if sp[0] == "ffcz.quantize"), key=lambda sp: sp[2])
+    assert [sp[4]["n"] for sp in quant] == [16**3, 16 * 16 * 9]  # spatial, then half spectrum
+    blob = FFCzBlob.from_bytes(traced["res"][uid].payload)
+    assert [sp[4]["active"] for sp in quant] == [blob.spat_edits.n_active, blob.freq_edits.n_active]
+    for sp in quant:
+        assert set(sp[4]) == {"n", "nonzero", "active"}
+        assert 0 < sp[4]["active"] <= sp[4]["nonzero"] <= sp[4]["n"]
+
+
+def test_base_pass_keeps_the_reconstruction(traced):
+    """szlike hands back the reconstruction it quantised against: every
+    request's ``ffcz.base`` says so, and each (one field, two pencil
+    tensors) counts ``base_recon_kept`` once."""
+    bases = [sp for sp in traced["spans"] if sp[0] == "ffcz.base"]
+    assert len(bases) == 3 and all(sp[4] == {"recon": "kept"} for sp in bases)
+    assert traced["counters"]["base_recon_kept"] == 3
+    assert traced["counters"]["base_recon_decoded"] == 0
 
 
 def test_deflate_spans_count_their_chunks(traced, tmp_path):
@@ -290,6 +319,15 @@ def test_queue_and_handoff_over_depth_two(monkeypatch):
     step 3: BACK C from 23."""
     svc, uids = _timed_service(2, monkeypatch)
     assert _waits(svc, uids) == [(0.0, 1.0), (1.0, 11.0), (12.0, 10.0)]
+
+
+def test_a_codec_without_a_reconstruction_decodes(monkeypatch):
+    """A base codec with only compress and decompress takes the decode
+    path: each of the three fields counts ``base_recon_decoded`` once."""
+    svc, uids = _timed_service(2, monkeypatch)
+    _waits(svc, uids)
+    assert svc.counters["base_recon_decoded"] == 3
+    assert svc.counters["base_recon_kept"] == 0
 
 
 def test_no_handoff_at_depth_one(monkeypatch):
