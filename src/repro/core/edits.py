@@ -130,7 +130,8 @@ def encode_edits(
     # a zero edit quantizes to code 0, so only the nonzero components are
     # quantized; the active ones are those whose codes are not all zero
     nonzero = np.flatnonzero(flat)
-    b = bound.ravel()[nonzero] if bound.size > 1 else bound
+    b = bound.ravel()
+    b = b[nonzero] if b.size > 1 else b
     vals = flat[nonzero]
     with span("ffcz.quantize", n=int(flat.size), nonzero=int(nonzero.size)) as sp:
         if is_complex:

@@ -127,6 +127,7 @@ EDIT_STREAMS = [
     ((24, 17, 9), 0.05, 1e-7),
     ((30, 40), 0.5, 2.0),
     ((30, 40), 0.0, 1.0),
+    ((1, 1), 1.0, 0.5),
 ]
 
 
