@@ -9,7 +9,21 @@ Everything a cell is made of is found by name from ``BENCHMARK.json``:
                               is made, submitted, warmed up and checked
 * ``traffic/<traffic>.json``  the mix, read by the one generator ``loadgen``
 * ``metrics/<metric>.py``     one reader per metric, ``read(run)`` -> value
-                              or None when the cell has nothing to read
+                              or None when the cell has nothing to read; a
+                              reader of the program's own output names it
+                              as ``SOURCE``: ``("span", name)``,
+                              ``("counter", key)``, ``("timer", key)`` or
+                              ``("stat", RequestStats field)``
+
+A metric that reads None is an error, with one exception: in a traced run
+a per-layer metric whose ``SOURCE`` the program under test never writes (no
+such span anywhere in the trace, no such key or field) is left out of the
+line, and one line of standard error names it.  So a parent commit that
+predates a span or a counter runs the cell that reads it; a source the
+program writes but that holds nothing in the window is still an error.
+A traced run keeps both the device's time (``trace_reduce.Summary``) and
+the program's spans (``stages.ProgramTrace``) from one load of the trace,
+and names the device's idle gaps by the stages open in them.
 
 A closed loop's window opens at its first completion (which is also its
 warm-up) and closes at the first completion at least ``--seconds`` later,
@@ -183,6 +197,7 @@ class Run:
         self.svc = None
         self.window: Optional[Window] = None
         self.summary = None  # trace_reduce.Summary of a traced run
+        self.program = None  # stages.ProgramTrace of a traced run
         self.setup_s: Optional[float] = None
         self.memory_peak_bytes: Optional[int] = None
         self.device: dict = {}
@@ -246,13 +261,21 @@ class Run:
             return
         import jax
 
-        from perfbench import trace_reduce
+        from perfbench import stages, trace_reduce
 
         jax.profiler.stop_trace()
+        t0 = time.perf_counter()
         try:
-            self.summary = trace_reduce.reduce(trace_reduce.load(trace_reduce.find_xplane(log_dir)))
+            pd = trace_reduce.load(trace_reduce.find_xplane(log_dir))
+            t1 = time.perf_counter()
+            self.program = stages.ProgramTrace.from_profile(pd)
+            t2 = time.perf_counter()
+            self.summary = trace_reduce.reduce(pd, name_gap=self.program.name_gap)
         finally:
             shutil.rmtree(log_dir, ignore_errors=True)
+        print(f"trace read in {time.perf_counter() - t0:.3f}s: load {t1 - t0:.3f}s, "
+              f"{len(self.program.spans)} program spans {t2 - t1:.3f}s, "
+              f"device reduction {time.perf_counter() - t2:.3f}s", file=sys.stderr)
 
     def run_window(self) -> Window:
         loop = self.traffic.get("loop")
@@ -433,10 +456,30 @@ class Run:
         name = self.cell["name"]
         return [m for m in self.bench[key] if "workloads" not in m or name in m["workloads"]]
 
+    def writes(self, source) -> bool:
+        """Whether the program under test writes ``source``, a reader's
+        ``SOURCE``: a span anywhere in the trace, a key of the service's
+        counters or timers, a field of its responses' ``RequestStats``."""
+        kind, name = source
+        if kind == "span":
+            return self.program is not None and self.program.writes(name)
+        if kind in ("counter", "timer"):
+            return name in getattr(self.window, kind + "s")
+        if kind == "stat":
+            stats = next((r.resp.stats for r in self.window.requests
+                          if r.resp is not None and r.resp.stats is not None), None)
+            return stats is None or hasattr(stats, name)  # no response: cannot tell
+        raise ValueError(f"unknown source kind {kind!r} in {source!r}")
+
     def metrics(self) -> Dict[str, dict]:
         out = {}
         for m in self.metric_entries():
             reader = load_module(self.here / "metrics" / f"{m['name']}.py")
+            source = getattr(reader, "SOURCE", None)
+            if self.trace and source is not None and not self.writes(source):
+                print(f"metric {m['name']} left out: the program writes no {source[0]} "
+                      f"{source[1]!r}", file=sys.stderr)
+                continue
             value = reader.read(self)
             if value is None:
                 raise RuntimeError(f"metric {m['name']} has nothing to read in {self.cell['name']}")

@@ -4,6 +4,8 @@ traced window, over the fields the service completed there."""
 
 from perfbench import stages
 
+SOURCE = ("span", "ffcz.base")
+
 
 def read(run):
-    return stages.ms_per_field(run, "ffcz.base")
+    return stages.ms_per_field(run, SOURCE[1])

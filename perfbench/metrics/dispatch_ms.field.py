@@ -4,6 +4,8 @@ over the fields the service completed there."""
 
 from perfbench import stages
 
+SOURCE = ("span", "ffcz.dispatch")
+
 
 def read(run):
-    return stages.ms_per_field(run, "ffcz.dispatch")
+    return stages.ms_per_field(run, SOURCE[1])

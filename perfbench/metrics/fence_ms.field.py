@@ -4,6 +4,8 @@ the service completed there."""
 
 from perfbench import stages
 
+SOURCE = ("span", "ffcz.fence")
+
 
 def read(run):
-    return stages.ms_per_field(run, "ffcz.fence")
+    return stages.ms_per_field(run, SOURCE[1])

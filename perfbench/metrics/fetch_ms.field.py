@@ -4,6 +4,8 @@ window, over the fields the service completed there."""
 
 from perfbench import stages
 
+SOURCE = ("span", "ffcz.fetch")
+
 
 def read(run):
-    return stages.ms_per_field(run, "ffcz.fetch")
+    return stages.ms_per_field(run, SOURCE[1])

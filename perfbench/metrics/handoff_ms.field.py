@@ -5,6 +5,8 @@ window's completed fields submitted after it opened
 
 from perfbench import stages
 
+SOURCE = ("stat", "handoff_s")
+
 
 def read(run):
-    return stages.mean_wait_ms(run, "handoff_s")
+    return stages.mean_wait_ms(run, SOURCE[1])

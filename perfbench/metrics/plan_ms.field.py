@@ -4,6 +4,8 @@ service completed there (perfbench/stages.py)."""
 
 from perfbench import stages
 
+SOURCE = ("span", "ffcz.plan")
+
 
 def read(run):
-    return stages.ms_per_field(run, "ffcz.plan")
+    return stages.ms_per_field(run, SOURCE[1])

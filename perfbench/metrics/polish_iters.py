@@ -2,9 +2,10 @@
 spans that overlap the traced window over its ``ffcz.polish`` spans that
 overlap it (0.0 when every polish exits before its first round)."""
 
+SOURCE = ("span", "ffcz.polish")
+
 
 def read(run):
-    program = getattr(run, "program", None)
-    if program is None or run.cfg["kind"] != "field":
+    if run.program is None or run.cfg["kind"] != "field":
         return None
-    return program.rounds_per_polish()
+    return run.program.rounds_per_polish()

@@ -4,6 +4,8 @@ the traced window, over the fields the service completed there."""
 
 from perfbench import stages
 
+SOURCE = ("span", "ffcz.polish")
+
 
 def read(run):
-    return stages.ms_per_field(run, "ffcz.polish")
+    return stages.ms_per_field(run, SOURCE[1])

@@ -4,6 +4,8 @@ completed fields submitted after it opened (``stages.admitted_in_window``)."""
 
 from perfbench import stages
 
+SOURCE = ("stat", "queue_s")
+
 
 def read(run):
-    return stages.mean_wait_ms(run, "queue_s")
+    return stages.mean_wait_ms(run, SOURCE[1])

@@ -7,7 +7,8 @@
   compiled program (an XLA module such as ``jit__alternating_projection``);
 * per-operation self time (a ``while`` op less the ops of its body) and the
   longest idle gaps, each named by the benchmark's own host span
-  (``bench.*``) that was open in its middle.
+  (``bench.*``) that was open in its middle, and then, where the caller
+  asks, by the program's own spans (``stages.ProgramTrace.name_gap``).
 
 On a TPU each chip is a plane ``/device:TPU:<i>``; its ``XLA Ops`` line holds
 the operations and its ``XLA Modules`` line the program executions an
@@ -25,7 +26,7 @@ import glob
 import os
 import re
 from collections import defaultdict
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 WINDOW_SPAN = "bench.window"
@@ -186,17 +187,28 @@ def _name_gap(spans, gap: Interval) -> str:
     return min(covering)[1] if covering else "no bench span"
 
 
-def reduce(pd, top: int = 10) -> Summary:
-    """Busy time, idle share, per-module and per-op time, longest gaps."""
-    ops = device_ops(pd)
-    spans = host_spans(pd)
+def window(pd, spans=None, ops=None) -> Interval:
+    """The traced window (ns): the host span ``bench.window`` when the trace
+    has one, else the extent of the operations.  ``spans`` and ``ops``, where
+    given, are the trace's :func:`host_spans` and :func:`device_ops`."""
+    spans = host_spans(pd) if spans is None else spans
     windows = [(s, e) for name, s, e in spans if name == WINDOW_SPAN]
     if windows:
-        lo, hi = windows[0]
-    elif ops:
-        lo, hi = min(o.start for o in ops), max(o.end for o in ops)
-    else:
+        return windows[0]
+    ops = device_ops(pd) if ops is None else ops
+    if not ops:
         raise ValueError("the trace has no window span and no device operation")
+    return min(o.start for o in ops), max(o.end for o in ops)
+
+
+def reduce(pd, top: int = 10,
+           name_gap: Optional[Callable[[str, Interval], str]] = None) -> Summary:
+    """Busy time, idle share, per-module and per-op time, longest gaps.
+    ``name_gap(plain, gap)``, where given, renames each gap from its plain
+    ``bench.*`` name."""
+    ops = device_ops(pd)
+    spans = host_spans(pd)
+    lo, hi = window(pd, spans, ops)
     chips = sorted({o.chip for o in ops}) or ["none"]
     by_chip = defaultdict(list)
     by_module = defaultdict(list)
@@ -218,6 +230,9 @@ def reduce(pd, top: int = 10) -> Summary:
     for (_chip, module), iv in by_module.items():
         module_ns[module] += length(union(iv, lo, hi))
     idle = sorted(gaps(busy[chips[0]], lo, hi), key=lambda g: g[0] - g[1])[:top]
+    names = [_name_gap(spans, g) for g in idle]
+    if name_gap is not None:
+        names = [name_gap(name, g) for name, g in zip(names, idle)]
     return Summary(
         window_s=(hi - lo) * 1e-9,
         busy_s=sum(length(b) for b in busy.values()) / n * 1e-9,
@@ -225,7 +240,7 @@ def reduce(pd, top: int = 10) -> Summary:
         op_s={k: v / n * 1e-9 for k, v in op_ns.items()},
         module_calls={m: v / n for m, v in calls.items()},
         module_whole_s={m: v / n * 1e-9 for m, v in whole_ns.items()},
-        gaps=[(_name_gap(spans, g), (g[1] - g[0]) * 1e-9) for g in idle],
+        gaps=[(name, (g[1] - g[0]) * 1e-9) for name, g in zip(names, idle)],
         chips=n,
     )
 
